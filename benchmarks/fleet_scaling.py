@@ -106,8 +106,7 @@ def main() -> None:
     ap.add_argument("--osts", type=int, default=2,
                     help="OSTs (= OSC interfaces per client)")
     ap.add_argument("--backend", default="numpy",
-                    choices=("numpy", "jax", "pallas"),
-                    help="model backend (pallas = interpret mode on CPU)")
+                    choices=("numpy", "jax"), help="model backend")
     ap.add_argument("--quick", action="store_true",
                     help="sweep 16..128 clients only")
     args = ap.parse_args()
@@ -129,4 +128,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

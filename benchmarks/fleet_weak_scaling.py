@@ -11,20 +11,20 @@ scenario each) and grows the device count, so ideal weak scaling is a
 flat time — i.e. tuned interface-intervals/sec growing linearly with
 devices.
 
-On CPU the device counts are forced host devices
-(``--xla_force_host_platform_device_count``, set *before* jax imports —
-the reason this file parses argv at the top).  Forced host devices share
-the machine's physical cores: on a single-core box the shards serialize
-and the curve is flat-per-device rather than linear — the number to
-trust there is the per-interface cost and the max-fleet capacity, and
-the curve itself on multi-core hardware.
+The sweep runs over the devices JAX can see, and asking for more is an
+error.  On a CPU, more than one device comes only from the caller's
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``.  Forced host
+devices share the machine's physical cores: on a single-core box the
+shards serialize and the curve is flat-per-device rather than linear —
+the number to trust there is the per-interface cost and the max-fleet
+capacity, and the curve itself on multi-core hardware.
 
 The second phase lifts one mesh over *all* forced devices to the target
 fleet size (``--max-fleet`` interfaces, default 2^17) and completes a
 multi-interval tuned run — the O(10^5) capacity probe.
 
 Run:  PYTHONPATH=src python benchmarks/fleet_weak_scaling.py
-          [--devices 1 2 4 8] [--per-device 64] [--quick] [--json]
+          [--devices 1 2 4] [--per-device 64] [--quick] [--json]
 """
 
 from __future__ import annotations
@@ -42,8 +42,9 @@ N_INTERVALS = 4            # tuned intervals per timed run
 def _parse(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--devices", type=int, nargs="*", default=None,
-                    help="device counts to sweep (default 1 2 4 8; "
-                         "quick: 1 2)")
+                    help="device counts to sweep (default: every power "
+                         "of two up to the visible count; quick: the "
+                         "first two)")
     ap.add_argument("--per-device", type=int, default=None,
                     help="batch elements per device (default 64; "
                          "quick: 8)")
@@ -59,21 +60,13 @@ def _parse(argv=None) -> argparse.Namespace:
     return ap.parse_args(argv)
 
 
-def main(argv=None) -> None:
-    args = _parse(argv)
-    devices = args.devices or ([1, 2] if args.quick else [1, 2, 4, 8])
-    per_device = args.per_device or (8 if args.quick else 64)
-    max_fleet = (args.max_fleet if args.max_fleet is not None
-                 else (4096 if args.quick else 1 << 17))
+def run(devices=None, per_device: int = 64, clients: int = 8,
+        osts: int = 2, max_fleet: int = 1 << 17) -> dict:
+    """The weak-scaling sweep in this process; returns the result dict.
 
-    # forced host devices must be configured before jax initializes;
-    # respect a count the caller already forced (e.g. the CI job)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count="
-            f"{max(devices)}").strip()
-
+    ``devices`` defaults to every power of two up to the visible device
+    count; a requested count above it raises ``ValueError``.
+    """
     import numpy as np
 
     import jax
@@ -87,10 +80,16 @@ def main(argv=None) -> None:
     from loop_scaling import build_sim
 
     n_avail = jax.device_count()
-    devices = [d for d in devices if d <= n_avail]
+    if devices is None:
+        devices = [1 << k for k in range(n_avail.bit_length())]
+    too_many = [d for d in devices if d > n_avail]
+    if too_many:
+        raise ValueError(f"asked for {too_many} devices but {n_avail} are "
+                         f"visible (on a CPU, force more with XLA_FLAGS="
+                         f"--xla_force_host_platform_device_count=N)")
 
     model = get_model("jax")
-    sim = build_sim(args.clients, args.osts)
+    sim = build_sim(clients, osts)
     n = sim.n_osc
     table, wstate0 = table_from_sim(sim)
     elem = (table, sim.state, wstate0)
@@ -103,7 +102,7 @@ def main(argv=None) -> None:
     def timed_run(n_dev: int, b: int) -> float:
         mesh = fleet_mesh(n_dev)
         loop = FusedLoop(sim.params, sim.topo, TICKS_PER_INTERVAL, model,
-                         seg_backend="jax", batched=True, mesh=mesh)
+                         seg_backend="auto", batched=True, mesh=mesh)
         bt, bs, bw = lifted(b)
         loop.run(bt, bs, bw, N_INTERVALS)         # compile + warm
         t0 = time.perf_counter()
@@ -143,13 +142,32 @@ def main(argv=None) -> None:
               f"{N_INTERVALS} tuned intervals in {t:.2f} s "
               f"({rate:.0f} if-intervals/s)")
 
+    return {"schema": "dial-weak-scaling-v1",
+            "interfaces_per_element": n,
+            "per_device_elements": per_device,
+            "host_cores": os.cpu_count(),
+            "points": points, "max_fleet": probe}
+
+
+def main(argv=None) -> None:
+    args = _parse(argv)
+    devices = args.devices
+    if devices is None and args.quick:
+        import jax
+
+        devices = [1 << k for k in range(jax.device_count().bit_length())]
+        devices = devices[:2]
+    result = run(devices=devices,
+                 per_device=args.per_device or (8 if args.quick else 64),
+                 clients=args.clients, osts=args.osts,
+                 max_fleet=(args.max_fleet if args.max_fleet is not None
+                            else (4096 if args.quick else 1 << 17)))
     if args.json:
-        print(json.dumps({"schema": "dial-weak-scaling-v1",
-                          "interfaces_per_element": n,
-                          "per_device_elements": per_device,
-                          "host_cores": os.cpu_count(),
-                          "points": points, "max_fleet": probe}))
+        print(json.dumps(result))
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

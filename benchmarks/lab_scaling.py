@@ -38,7 +38,7 @@ TIMED_INTERVALS = 2
 BASE_SCENARIO = "noisy_neighbor"
 
 
-def bench(batch_size: int, seg_backend: str = "jax",
+def bench(batch_size: int, seg_backend: str = "auto",
           base: str = BASE_SCENARIO) -> dict:
     specs = variants(get_scenario(base), batch_size, seed=11)
     interval_s = TICKS_PER_INTERVAL * 0.005
@@ -77,14 +77,14 @@ def bench(batch_size: int, seg_backend: str = "jax",
     }
 
 
-def run(scales=(8, 32, 128), seg_backend: str = "jax") -> list[dict]:
+def run(scales=(8, 32, 128), seg_backend: str = "auto") -> list[dict]:
     return [bench(b, seg_backend) for b in scales]
 
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--batches", type=int, nargs="*", default=[8, 32, 128])
-    ap.add_argument("--seg-backend", default="jax")
+    ap.add_argument("--seg-backend", default="auto")
     ap.add_argument("--quick", action="store_true",
                     help="sweep 8..32 scenarios only")
     ap.add_argument("--json", action="store_true")
@@ -107,4 +107,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -104,7 +104,7 @@ def _bench_fused(n_clients: int, model, seg_backend: str) -> float:
     return time.perf_counter() - t0
 
 
-def bench(n_osc: int, seg_backend: str = "jax", model=None) -> dict:
+def bench(n_osc: int, seg_backend: str = "auto", model=None) -> dict:
     model = model if model is not None else get_model("jax")
     n_clients = n_osc // N_OSTS
     t_np = _bench_host_numpy(n_clients, model)
@@ -121,7 +121,7 @@ def bench(n_osc: int, seg_backend: str = "jax", model=None) -> dict:
     }
 
 
-def run(scales=(64, 256, 1024), seg_backend: str = "jax") -> list[dict]:
+def run(scales=(64, 256, 1024), seg_backend: str = "auto") -> list[dict]:
     model = get_model("jax")
     return [bench(n, seg_backend, model) for n in scales]
 
@@ -129,7 +129,7 @@ def run(scales=(64, 256, 1024), seg_backend: str = "jax") -> list[dict]:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--oscs", type=int, nargs="*", default=[64, 256, 1024])
-    ap.add_argument("--seg-backend", default="jax")
+    ap.add_argument("--seg-backend", default="auto")
     ap.add_argument("--quick", action="store_true",
                     help="sweep 64..256 interfaces only")
     ap.add_argument("--json", action="store_true",
@@ -156,4 +156,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -49,7 +49,7 @@ def _time_loop(model, trace, seconds: float, interval: float,
     steps = max(int(round(interval / proto.params.tick)), 1)
     n_intervals = int(round(seconds / interval))
     loop = FusedLoop(proto.params, proto.topo, steps, model,
-                     seg_backend="jax", trace=trace)
+                     seg_backend="auto", trace=trace)
     walls = []
     for _ in range(reps + 1):
         s = _sim()
@@ -102,4 +102,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -79,7 +79,7 @@ def _drive(groups, model, seg_backend: str) -> None:
                   seg_backend=seg_backend, fused=True)
 
 
-def bench(n_scenarios: int, seg_backend: str = "jax",
+def bench(n_scenarios: int, seg_backend: str = "auto",
           model: DIALModel | None = None) -> dict:
     specs = [generate_spec(CATALOG, i) for i in range(n_scenarios)]
     model = model or _tiny_model()
@@ -106,7 +106,7 @@ def bench(n_scenarios: int, seg_backend: str = "jax",
     return out
 
 
-def run(scales=(8, 16, 32), seg_backend: str = "jax") -> list[dict]:
+def run(scales=(8, 16, 32), seg_backend: str = "auto") -> list[dict]:
     model = _tiny_model()
     return [bench(n, seg_backend, model=model) for n in scales]
 
@@ -114,7 +114,7 @@ def run(scales=(8, 16, 32), seg_backend: str = "jax") -> list[dict]:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--catalogs", type=int, nargs="*", default=[8, 16, 32])
-    ap.add_argument("--seg-backend", default="jax")
+    ap.add_argument("--seg-backend", default="auto")
     ap.add_argument("--quick", action="store_true",
                     help="sweep 8..16 scenarios only")
     ap.add_argument("--json", action="store_true")
@@ -145,4 +145,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

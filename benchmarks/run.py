@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import platform
 import time
 
@@ -32,6 +33,7 @@ def main(argv=None) -> None:
 
     import benchmarks.fig3_dlio as fig3
     import benchmarks.fleet_scaling as fleet
+    import benchmarks.fleet_weak_scaling as fws
     import benchmarks.lab_scaling as labsc
     import benchmarks.loop_scaling as loopsc
     import benchmarks.obs_overhead as obsov
@@ -143,21 +145,11 @@ def main(argv=None) -> None:
              "exact_forests_per_s": round(rt["exact_forests_per_s"], 2),
              "fast_speedup": round(rt["fast_speedup"], 1)})
 
-    # a fresh process: the weak-scaling sweep forces host devices via
-    # XLA_FLAGS, which only takes effect before jax initializes — and
-    # the benchmarks above already initialized it here
-    import os
-    import subprocess
-    import sys
-
+    # in this process, over the devices JAX already sees: a second JAX
+    # process could not reach a chip this one holds
     t0 = time.time()
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "fleet_weak_scaling.py"), "--json"],
-        capture_output=True, text=True, check=True)
+    wk = fws.run()
     el = time.time() - t0
-    wk = json.loads(out.stdout.strip().splitlines()[-1])
     last, probe = wk["points"][-1], wk["max_fleet"]
     _record(records, "fleet_weak_scaling", el * 1e6,
             {"max_devices": last["devices"],
@@ -166,25 +158,6 @@ def main(argv=None) -> None:
              "host_cores": wk["host_cores"],
              "max_fleet_interfaces": probe["interfaces"],
              "max_fleet_seconds": probe["seconds"]})
-
-    # same fresh-process constraint: perf_iterations forces 512 host
-    # devices at import
-    t0 = time.time()
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "perf_iterations.py"), "--quick", "--json"],
-        capture_output=True, text=True, check=True)
-    el = time.time() - t0
-    pi = json.loads(out.stdout.strip().splitlines()[-1])
-    base = pi["measures"]["A_baseline"]
-    pad = pi["measures"]["A_padded_ep"]
-    _record(records, "perf_iterations", el * 1e6,
-            {"a_baseline_dominant": base["dominant"],
-             "a_baseline_mfu_bound": round(base["mfu_bound"], 3),
-             "a_padded_ep_mfu_bound": round(pad["mfu_bound"], 3),
-             "a_mfu_gain": round(pad["mfu_bound"]
-                                 / max(base["mfu_bound"], 1e-9), 2)})
 
     if args.json:
         from repro.obs.timers import collect_provenance
@@ -220,4 +193,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

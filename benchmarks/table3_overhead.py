@@ -2,8 +2,7 @@
 
 Wall-clock times for snapshot creation, model inference over the whole
 configuration space, and the end-to-end tuning round — per operation type,
-for the numpy reference backend, the jitted JAX path, and the Pallas
-kernel (interpret mode on CPU; compiled on TPU).
+for the numpy reference backend and the jitted JAX path.
 
 Since the fleet refactor, :class:`DIALAgent` scores all of its client's
 interfaces per tick in one batch, so the reported figures are the batch
@@ -85,7 +84,7 @@ def run_fused(model_path: str = "models/dial", sharded: bool = False,
     out = {}
     for name, tuned in (("tuned", True), ("engine_only", False)):
         loop = FusedLoop(sim.params, sim.topo, steps,
-                         model if tuned else None, seg_backend="jax",
+                         model if tuned else None, seg_backend="auto",
                          tuned=tuned, batched=sharded, mesh=mesh)
         walls = []
         for rep in range(2):            # rep 0 pays compilation
@@ -107,7 +106,7 @@ def run_fused(model_path: str = "models/dial", sharded: bool = False,
 
 
 def main():
-    for backend in ("numpy", "jax", "pallas"):
+    for backend in ("numpy", "jax"):
         res = run(backend=backend)
         for op in ("read", "write"):
             r = res[op]
@@ -126,4 +125,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

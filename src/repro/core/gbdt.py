@@ -2,8 +2,8 @@
 
 Training is histogram-based boosting with logistic loss, implemented in
 numpy (no sklearn offline).  The fitted forest is exported in a *dense
-complete-binary-tree layout* designed for the TPU inference kernel
-(:mod:`repro.kernels.gbdt_forest`):
+complete-binary-tree layout* that the jitted inference traversal
+(:mod:`repro.kernels.gbdt_forest`) walks:
 
     feature   : (T, 2^D - 1) int32    -- split feature per internal node
     threshold : (T, 2^D - 1) float32  -- split threshold (+inf = pass left)
@@ -28,11 +28,18 @@ def _sigmoid(x: np.ndarray) -> np.ndarray:
     return 1.0 / (1.0 + np.exp(-np.clip(x, -30, 30)))
 
 
-# Split gains are rounded to this many decimals before argmax so that
-# mathematically-equal candidates stay tied under any float summation
-# order; ties then break on (feature, bin) order in every trainer
-# backend (numpy loop and repro.learn.boost must agree split for split).
-GAIN_DECIMALS = 9
+def quantize_gains(gain):
+    """Split gains as the argmax compares them: rounded to float32.
+
+    Mathematically-equal candidates (e.g. two features isolating the same
+    sample set) then stay tied under any float summation order, and ties
+    break on (feature, bin) order in every trainer backend (the numpy
+    loop and :mod:`repro.learn.boost` must agree split for split).  The
+    rounding is relative: gains grow with the node's sample count, and
+    rounding to a fixed number of decimals let the ~1e-13 relative
+    summation noise of gains in the thousands straddle a rounding step.
+    """
+    return gain.astype(np.float32)
 
 
 # ---------------------------------------------------------------------- #
@@ -160,15 +167,11 @@ class GBDTClassifier:
                 h_t = np.where(mask, h, 0.0)
             else:
                 g_t, h_t = g, h
-            tf, tt, tl = self._build_tree(Xb, g_t, h_t, edges)
+            tf, tt, tl, leaf_of = self._build_tree(Xb, g_t, h_t, edges)
             feats[t], thrs[t], leaves[t] = tf, tt, tl
-            # update margins with the new tree only
-            idx = np.zeros(n, dtype=np.int64)
-            for _ in range(p.max_depth):
-                f = tf[idx]
-                go_right = X[np.arange(n), f] > tt[idx]
-                idx = 2 * idx + 1 + go_right
-            F += tl[idx - n_internal]
+            # update margins with the new tree only, on the partition its
+            # leaves were fitted to
+            F += tl[leaf_of]
 
         self.forest = DenseForest(
             feature=feats, threshold=thrs, leaf=leaves, base_score=base,
@@ -224,11 +227,7 @@ class GBDTClassifier:
                               - G ** 2 / (H + lam))
                 gain = np.where((HL >= p.min_child_hess)
                                 & (HR >= p.min_child_hess), gain, -np.inf)
-                # quantize so mathematically-tied candidates (e.g. two
-                # features isolating the same sample set) compare equal
-                # regardless of float summation order, and the (feature,
-                # bin) tie-break below is stable across trainer backends
-                gain = np.round(gain, GAIN_DECIMALS)
+                gain = quantize_gains(gain)
                 for j in range(n_level):
                     b = int(np.argmax(gain[j]))
                     gj = gain[j, b]
@@ -285,7 +284,7 @@ class GBDTClassifier:
                 while anc > 0 and anc not in node_value:
                     anc = (anc - 1) // 2
                 leaf[j] = node_value.get(anc, 0.0)
-        return feature, threshold, leaf
+        return feature, threshold, leaf, loc
 
     # ------------------------------------------------------------------ #
     def predict_proba(self, X: np.ndarray) -> np.ndarray:

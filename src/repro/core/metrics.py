@@ -198,15 +198,18 @@ def _log2_knob(x, xp):
     that error survives the float32 feature cast through the θ-delta
     subtraction (6.0 - 5.999…e0 ≈ 9e-16 instead of exactly 0.0) and can
     flip GBDT splits whose threshold sits at 0.  Knob values are powers
-    of two (the Θ grid), where ``frexp`` recovers the exponent exactly;
-    non-power-of-two values (only reachable by writing knobs outside Θ)
-    fall back to the backend ``log2``.
+    of two (the Θ grid), whose exponent is counted exactly by comparing
+    against the powers ``2**0 .. 2**62``; other values (only reachable
+    by writing knobs outside Θ) fall back to the backend ``log2``.
+    Compares only: ``frexp`` lowers to a 64-bit bitcast, which the TPU
+    compiler cannot emulate.
     """
     if xp is np:
         return np.log2(x)
-    m, e = xp.frexp(x.astype(np.float64))
-    return xp.where(m == 0.5, (e - 1).astype(np.float64),
-                    xp.log2(x.astype(np.float64)))
+    x = x.astype(np.float64)
+    pows = xp.asarray(2.0 ** np.arange(63))
+    e = xp.maximum(xp.sum(x[..., None] >= pows, axis=-1) - 1, 0)
+    return xp.where(x == pows[e], e.astype(np.float64), xp.log2(x))
 
 
 def snapshot_arrays(prev, cur, xp=np):

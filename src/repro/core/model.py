@@ -7,7 +7,6 @@ the *entire* configuration space against the current history in one shot.
 Backends:
     'numpy'  -- DenseForest.predict_proba (always available; the oracle)
     'jax'    -- jitted gather-based traversal (repro.kernels.gbdt_forest.ops)
-    'pallas' -- the TPU kernel in interpret mode on CPU, compiled on TPU
 
 The batched evaluation (n_oscs x |Theta| rows per tick) is the paper's
 inference hot spot (Table III: ~10-13.5 ms per interface); the TPU
@@ -56,6 +55,9 @@ class DIALModel:
     train_meta: dict = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
+        if self.backend not in ("numpy", "jax"):
+            raise ValueError(f"unknown DIALModel backend {self.backend!r} "
+                             f"(want 'numpy' or 'jax')")
         self._theta_feats = self.space.as_features()  # (|Theta|, 2) log2
         self._jax_fns = {}
         # bumped by update_forests: consumers that baked forest copies
@@ -115,7 +117,7 @@ class DIALModel:
 
         ``X_read`` / ``X_write`` are the stacked (interface x config)
         feature rows from :func:`repro.core.metrics.fleet_feature_matrix`.
-        On the jax/pallas backends both ops are fused into **one** launch
+        On the jax backend both ops are fused into **one** launch
         with a per-row forest selector (the two forests live stacked on
         device); the numpy backend scores each forest once — still one
         batched traversal per op, never one call per interface.
@@ -130,8 +132,7 @@ class DIALModel:
         key = ("fleet", self.backend)
         if key not in self._jax_fns:
             self._jax_fns[key] = kops.make_fleet_predictor(
-                self.read_forest, self.write_forest,
-                use_pallas=(self.backend == "pallas"))
+                self.read_forest, self.write_forest)
         return self._jax_fns[key](X_read, X_write)
 
     def paired_arrays(self):
@@ -158,8 +159,7 @@ class DIALModel:
         from repro.kernels.gbdt_forest import ops as kops  # lazy import
         key = (op, self.backend)
         if key not in self._jax_fns:
-            self._jax_fns[key] = kops.make_predictor(
-                f, use_pallas=(self.backend == "pallas"))
+            self._jax_fns[key] = kops.make_predictor(f)
         return np.asarray(self._jax_fns[key](np.asarray(X, dtype=np.float32)))
 
     # ------------------------------------------------------------------ #

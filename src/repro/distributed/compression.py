@@ -81,8 +81,6 @@ def make_dp_train_grads(loss_fn, mesh, axis_name: str = "data",
     reductions (this is the explicit-collective alternative for the
     cross-pod DCN hop).
     """
-    from jax.experimental.shard_map import shard_map
-
     def local(params, batch, error_bufs):
         # error buffers carry a leading device axis (sharded over
         # axis_name): strip it inside, restore it on the way out
@@ -96,7 +94,7 @@ def make_dp_train_grads(loss_fn, mesh, axis_name: str = "data",
                 jax.tree.map(lambda x: x[None], ebufs))
 
     def apply(params, batch, error_bufs):
-        sm = shard_map(
+        sm = jax.shard_map(
             local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),
                       jax.tree.map(lambda _: P(axis_name), batch),
@@ -104,7 +102,7 @@ def make_dp_train_grads(loss_fn, mesh, axis_name: str = "data",
             out_specs=(P(),
                        jax.tree.map(lambda _: P(), params),
                        jax.tree.map(lambda _: P(axis_name), error_bufs)),
-            check_rep=False)
+            check_vma=False)
         return sm(params, batch, error_bufs)
 
     return apply

@@ -95,7 +95,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     softcap: float = 0.0, scale: float | None = None,
                     block_q: int = 128, block_kv: int = 128,
-                    interpret: bool = True):
+                    interpret: bool):
     """Flash attention forward.
 
     Args:

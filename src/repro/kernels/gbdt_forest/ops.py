@@ -8,11 +8,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.gbdt_forest import kernel as _kernel
 from repro.kernels.gbdt_forest import ref as _ref
 
 
-def make_predictor(forest, use_pallas: bool = False, interpret: bool = True):
+def make_predictor(forest):
     """Build a jitted ``X -> probabilities`` closure for a DenseForest.
 
     The forest arrays are closed over (donated to the device once);
@@ -24,18 +23,10 @@ def make_predictor(forest, use_pallas: bool = False, interpret: bool = True):
     base = float(forest.base_score)
     depth = int(forest.depth)
 
-    if use_pallas:
-        def margin_fn(x):
-            return _kernel.forest_margin(x, feature, threshold, leaf, base,
-                                         depth, interpret=interpret)
-    else:
-        def margin_fn(x):
-            return _ref.forest_margin_ref(x, feature, threshold, leaf, base,
-                                          depth)
-
     @jax.jit
     def predict(x):
-        m = margin_fn(x.astype(jnp.float32))
+        m = _ref.forest_margin_ref(x.astype(jnp.float32), feature, threshold,
+                                   leaf, base, depth)
         return 1.0 / (1.0 + jnp.exp(-jnp.clip(m, -30.0, 30.0)))
 
     return predict
@@ -112,8 +103,7 @@ def _round_up_pow2(n: int, floor: int = 32) -> int:
     return cap
 
 
-def make_fleet_predictor(read_forest, write_forest, use_pallas: bool = False,
-                         interpret: bool = True):
+def make_fleet_predictor(read_forest, write_forest):
     """Build the fleet scorer: ``(X_read, X_write) -> (p_read, p_write)``.
 
     Both ops' (interface x config) rows are fused into one padded batch
@@ -129,19 +119,10 @@ def make_fleet_predictor(read_forest, write_forest, use_pallas: bool = False,
     leaf = jnp.asarray(leaf)
     base = jnp.asarray(base)
 
-    if use_pallas:
-        def margin_fn(x, op):
-            return _kernel.paired_forest_margin(
-                x, op, feature, threshold, leaf, base, depth,
-                interpret=interpret)
-    else:
-        def margin_fn(x, op):
-            return _ref.paired_forest_margin_ref(
-                x, op, feature, threshold, leaf, base, depth)
-
     @jax.jit
     def _predict(x, op):
-        m = margin_fn(x.astype(jnp.float32), op)
+        m = _ref.paired_forest_margin_ref(x.astype(jnp.float32), op, feature,
+                                          threshold, leaf, base, depth)
         return 1.0 / (1.0 + jnp.exp(-jnp.clip(m, -30.0, 30.0)))
 
     def predict(x_read: np.ndarray, x_write: np.ndarray):

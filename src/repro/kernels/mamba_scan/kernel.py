@@ -48,8 +48,8 @@ def _mamba_kernel(u_ref, delta_ref, a_ref, b_ref, c_ref, d_ref, o_ref):
     o_ref[0] = ys.astype(o_ref.dtype)
 
 
-def selective_scan(u, delta, A, B, C, D, *, block_d: int = DEFAULT_BLOCK_D,
-                   interpret: bool = True):
+def selective_scan(u, delta, A, B, C, D, *, interpret: bool,
+                   block_d: int = DEFAULT_BLOCK_D):
     """Selective scan via pl.pallas_call; args as in ref.selective_scan_ref."""
     bt, s, dm = u.shape
     n = A.shape[1]

@@ -32,7 +32,7 @@ def _rglru_kernel(x_ref, a_ref, o_ref):
     o_ref[0] = hs.astype(o_ref.dtype)
 
 
-def rglru(x, a, *, block_d: int = DEFAULT_BLOCK_D, interpret: bool = True):
+def rglru(x, a, *, interpret: bool, block_d: int = DEFAULT_BLOCK_D):
     """RG-LRU scan via pl.pallas_call; args as in ref.rglru_ref."""
     bt, s, dm = x.shape
     block_d = min(block_d, dm)

@@ -16,6 +16,12 @@ The grid walks (feature, sample-tile); the per-feature (C, S) output
 block stays VMEM-resident across all sample tiles, and all C channels
 ride one matmul, so a whole level's gradient+hessian+count histograms
 are a single launch.
+
+Layout: bin codes are read feature-major as ``(F, 1, E)``, node ids as
+a ``(1, E)`` row, and the output is written as ``(F, C, S)``, so every
+block's last two dimensions are either whole array dimensions or
+multiples of the TPU's (8, 128) tile — also when ``vmap`` prepends a
+forest axis.  A ``(BE, 1)`` column of an ``(E, F)`` array is not.
 """
 
 from __future__ import annotations
@@ -34,9 +40,9 @@ def _tree_histogram_kernel(values_ref, bins_ref, node_ref, out_ref, *,
     """One grid step: fold a (C, BE) value tile of one feature into the
     feature's resident (C, S) histogram block."""
     s = n_nodes * n_bins
-    values = values_ref[...].astype(jnp.float32)       # (C, BE)
-    bins = bins_ref[...][:, 0]                         # (BE,) int32
-    node = node_ref[...]                               # (BE,) int32
+    values = values_ref[...]                           # (C, BE) float32
+    bins = bins_ref[0, :]                              # (BE,) int32
+    node = node_ref[0, :]                              # (BE,) int32
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -48,16 +54,17 @@ def _tree_histogram_kernel(values_ref, bins_ref, node_ref, out_ref, *,
               ).astype(jnp.float32)                    # (BE, S)
     partial = jnp.dot(values, onehot,
                       preferred_element_type=jnp.float32)  # (C, S)
-    out_ref[...] += partial[:, None, :]
+    out_ref[...] += partial
 
 
-def tree_histogram(values, bins, node, n_nodes: int, n_bins: int,
-                   block_e: int = DEFAULT_BLOCK_E, interpret: bool = True):
+def tree_histogram(values, bins, node, n_nodes: int, n_bins: int, *,
+                   interpret: bool, block_e: int = DEFAULT_BLOCK_E):
     """Multi-channel (node, feature, bin) histograms via one-hot matmuls.
 
     Args/shapes as :func:`repro.kernels.tree_histogram.ref
     .tree_histogram_np`; returns ``(C, n_nodes, F, n_bins)`` float32.
-    ``interpret=True`` executes on CPU (validation); on TPU pass False.
+    ``interpret=True`` runs the kernel body in the Pallas interpreter
+    (CPU validation); the TPU compiles it with ``interpret=False``.
     Sample padding uses node id ``n_nodes`` so its one-hot row is all
     zeros and contributes nothing.
     """
@@ -73,6 +80,7 @@ def tree_histogram(values, bins, node, n_nodes: int, n_bins: int,
         bins = jnp.pad(bins, ((0, e_pad), (0, 0)))
         node = jnp.pad(node, (0, e_pad), constant_values=n_nodes)
     grid = (f, (e + e_pad) // block_e)
+    bins_fm = bins.T[:, None, :]                       # (F, 1, E)
 
     out = pl.pallas_call(
         functools.partial(_tree_histogram_kernel,
@@ -80,13 +88,14 @@ def tree_histogram(values, bins, node, n_nodes: int, n_bins: int,
         grid=grid,
         in_specs=[
             pl.BlockSpec((c, block_e), lambda fi, i: (0, i)),   # values
-            pl.BlockSpec((block_e, 1), lambda fi, i: (i, fi)),  # bin codes
-            pl.BlockSpec((block_e,), lambda fi, i: (i,)),       # node ids
+            pl.BlockSpec((None, 1, block_e),
+                         lambda fi, i: (fi, 0, i)),             # bin codes
+            pl.BlockSpec((1, block_e), lambda fi, i: (0, i)),   # node ids
         ],
-        out_specs=pl.BlockSpec((c, 1, s), lambda fi, i: (0, fi, 0)),
-        out_shape=jax.ShapeDtypeStruct((c, f, s), jnp.float32),
+        out_specs=pl.BlockSpec((None, c, s), lambda fi, i: (fi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((f, c, s), jnp.float32),
         interpret=interpret,
         name="tree_histogram_onehot",
-    )(values, bins, node)
-    # (C, F, n_nodes * n_bins) -> (C, n_nodes, F, n_bins)
-    return jnp.transpose(out.reshape(c, f, n_nodes, n_bins), (0, 2, 1, 3))
+    )(values, bins_fm, node[None])
+    # (F, C, n_nodes * n_bins) -> (C, n_nodes, F, n_bins)
+    return jnp.transpose(out.reshape(f, c, n_nodes, n_bins), (1, 2, 0, 3))

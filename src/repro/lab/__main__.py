@@ -231,7 +231,7 @@ def main(argv=None) -> None:
                     help="campaign artifact root to resolve models from")
     ev.add_argument("--seconds", type=float, default=10.0)
     ev.add_argument("--interval", type=float, default=0.5)
-    ev.add_argument("--seg-backend", default="jax")
+    ev.add_argument("--seg-backend", default="auto")
     ev.add_argument("--no-fused", action="store_true",
                     help="use the per-interval host loop instead of the "
                          "single-dispatch device-resident loop")
@@ -337,7 +337,7 @@ def main(argv=None) -> None:
                          "record, Perfetto marker track, md section)")
     tr.add_argument("--seconds", type=float, default=10.0)
     tr.add_argument("--interval", type=float, default=0.5)
-    tr.add_argument("--seg-backend", default="jax")
+    tr.add_argument("--seg-backend", default="auto")
     tr.add_argument("--model", default=None,
                     help="DIALModel prefix (default: evaluate's model "
                          "resolution order)")
@@ -364,7 +364,7 @@ def main(argv=None) -> None:
     dg.add_argument("--max-evidence", type=int, default=8,
                     help="evidence rows kept per diagnosis (total is "
                          "always recorded)")
-    dg.add_argument("--seg-backend", default="jax")
+    dg.add_argument("--seg-backend", default="auto")
     dg.add_argument("--model", default=None,
                     help="DIALModel prefix (default: evaluate's model "
                          "resolution order)")
@@ -398,4 +398,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -39,7 +39,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.fleet import FleetAgent
 from repro.core.tuner import TunerParams
@@ -343,7 +342,7 @@ class BatchEngine:
     """
 
     def __init__(self, params: SimParams, topo: SimTopo, n_ticks: int,
-                 seg_backend: str = "jax"):
+                 seg_backend: str = "auto"):
         self.params = params
         self.topo = topo
         self.n_ticks = int(n_ticks)
@@ -367,7 +366,7 @@ class BatchEngine:
     def run_interval(self, table: WorkloadTable, state: SimState,
                      wstate: WorkloadState, sched: Disturbance):
         """Advance every element one interval; numpy in, numpy out."""
-        with enable_x64():
+        with jax.enable_x64(True):
             args = jax.tree.map(jnp.asarray, (table, state, wstate, sched))
             jstate, jws = self._run(*args)
             jstate, jws = jax.tree.map(
@@ -441,7 +440,7 @@ class BatchPort:
 
 
 def run_batch(batch: ScenarioBatch, model=None, seconds: float = 10.0,
-              interval: float = 0.5, seg_backend: str = "jax",
+              interval: float = 0.5, seg_backend: str = "auto",
               tuner_params: TunerParams | None = None,
               tune_cols=None, engine: BatchEngine | None = None,
               fused: bool = False, mesh=None, trace=None,
@@ -541,6 +540,41 @@ def run_batch(batch: ScenarioBatch, model=None, seconds: float = 10.0,
         fleet.trace = fleet.tracer.run_trace(
             fleet.oscs, interval, batch.params.tick)
     return fleet
+
+
+def run_oracle(built: BuiltScenario, model, seconds: float = 10.0,
+               interval: float = 0.5,
+               tuner_params: TunerParams | None = None):
+    """The numpy oracle of a DIAL-tuned run of one scenario.
+
+    Each interval steps the numpy engine
+    (:func:`repro.pfs.workloads.run_interval` over
+    :func:`repro.pfs.state.engine_step`) through the scenario's
+    disturbance schedule, then one :class:`FleetAgent` tick over every
+    interface.  Shares no code with the jitted engine or the fused loop,
+    so a fused run is checked against it: θ trajectories exact and
+    simulated MB/s within 1e-6.  Returns ``(fleet, batch)``: the agent
+    with its per-interval decisions, and a one-element
+    :class:`ScenarioBatch` holding the final state (``batch.throughput``).
+    """
+    from repro.pfs.workloads import run_interval
+
+    steps = max(int(round(interval / built.params.tick)), 1)
+    n_intervals = int(round(seconds / interval))
+    batch = stack_scenarios([built])
+    fleet = FleetAgent(BatchPort(batch), model, tuner_params=tuner_params)
+    sched = built.schedule(0, n_intervals * steps)
+    first = lambda tree: jax.tree.map(lambda a: np.array(a[0]), tree)
+    for i in range(n_intervals):
+        state, wstate = run_interval(
+            built.params, built.topo, built.table, first(batch.state),
+            first(batch.wstate), steps,
+            schedule=jax.tree.map(
+                lambda a: a[i * steps:(i + 1) * steps], sched))
+        batch.state, batch.wstate = jax.tree.map(
+            lambda a: np.asarray(a)[None], (state, wstate))
+        fleet.tick()
+    return fleet, batch
 
 
 # compiled fused loops, reused across run_batch calls: scenarios that

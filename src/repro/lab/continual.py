@@ -77,7 +77,7 @@ def run_continual(spec: ScenarioSpec, model: DIALModel, *,
                   policy: OnlinePolicy | None = None,
                   gbdt_params: GBDTParams | None = None,
                   seed_data: dict | None = None,
-                  seg_backend: str = "jax",
+                  seg_backend: str = "auto",
                   tuner_params: TunerParams | None = None,
                   seed: int = 0) -> ContinualResult:
     """Drive one scenario with DIAL tuning and (optionally) online refit.
@@ -242,7 +242,7 @@ def run_comparison(name: str = "failing_ost", model: DIALModel | None = None,
                    policy: OnlinePolicy | None = None,
                    gbdt_params: GBDTParams | None = None,
                    seed_data: dict | None = None,
-                   seg_backend: str = "jax", smoke: bool = False) -> dict:
+                   seg_backend: str = "auto", smoke: bool = False) -> dict:
     """Frozen-model vs online-refit on one drifting scenario.
 
     Both runs start from the *same* forests (the online run swaps its
@@ -341,7 +341,7 @@ def run_hard_case_curriculum(report_path: str, model: DIALModel, *,
                              seconds: float = 12.0, interval: float = 0.5,
                              policy: OnlinePolicy | None = None,
                              gbdt_params: GBDTParams | None = None,
-                             seg_backend: str = "jax",
+                             seg_backend: str = "auto",
                              max_cases: int | None = None,
                              seed: int = 0) -> dict:
     """Close the triage loop: replay a fuzz report's losers as a

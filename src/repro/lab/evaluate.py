@@ -57,7 +57,7 @@ class ScenarioResult:
 
 def evaluate_scenario(spec: ScenarioSpec, model: DIALModel,
                       seconds: float = 10.0, interval: float = 0.5,
-                      seg_backend: str = "jax",
+                      seg_backend: str = "auto",
                       tuner_params: TunerParams | None = None,
                       fused: bool = True, mesh=None,
                       trace=None) -> ScenarioResult:
@@ -190,7 +190,7 @@ def _evaluate_catalog_ragged(specs, model: DIALModel, seconds: float,
 
 def evaluate(names=None, model: DIALModel | None = None,
              seconds: float = 10.0, interval: float = 0.5,
-             seg_backend: str = "jax", fused: bool = True,
+             seg_backend: str = "auto", fused: bool = True,
              mesh=None, ragged: bool = True) -> dict:
     """Run the catalog (default: every registered scenario) and return
     the report dict (rows + summary).

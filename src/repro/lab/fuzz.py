@@ -80,7 +80,7 @@ class FuzzConfig:
     max_events: int = 3
     stripe_all_prob: float = 0.5       # row stripes over all OSTs vs one
     max_batch_elems: int = 256         # chunk buckets beyond this
-    seg_backend: str = "jax"
+    seg_backend: str = "auto"
 
 
 #: CI-sized sweep: 64 scenarios, 3 s each, a 6-point static grid, two

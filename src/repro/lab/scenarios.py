@@ -339,6 +339,38 @@ def variants(spec: ScenarioSpec, n: int, seed: int = 0) -> list[ScenarioSpec]:
     return out
 
 
+BDCATS_MODES = ("partial", "strided", "full")
+
+
+def vpic_bdcats_deployment(n_clients: int, n_osts: int,
+                           stripe_count: int = 8) -> ScenarioSpec:
+    """H5bench VPIC-IO checkpoint writers beside BDCATS-IO readers at a
+    deployment's shape (not in the catalog: its size is the point).
+
+    The first half of the clients run ``vpic_write`` (1-, 2- and 3-D
+    particle layouts in turn), the second half ``bdcats_read`` in the
+    three BDCATS modes in turn.  Client ``c``'s file is striped over
+    ``stripe_count`` consecutive OSTs starting at ``c mod n_osts``, so
+    every OST carries writers and readers.
+    """
+    if not 0 < stripe_count <= n_osts:
+        raise ValueError(f"stripe_count {stripe_count} must be in "
+                         f"[1, n_osts={n_osts}]")
+    half = n_clients // 2
+    rows = []
+    for c in range(n_clients):
+        osts = tuple((c + j) % n_osts for j in range(stripe_count))
+        rows.append(vpic_write(c, dims=1 + c % 3, osts=osts) if c < half
+                    else bdcats_read(c, BDCATS_MODES[c % 3], osts=osts))
+    return ScenarioSpec(
+        name=f"vpic_bdcats_{n_clients}x{n_osts}",
+        n_clients=n_clients, n_osts=n_osts, workloads=tuple(rows),
+        description=f"H5bench VPIC-IO checkpoint (half the clients) and "
+                    f"BDCATS-IO reads (the other half), each file striped "
+                    f"over {stripe_count} consecutive OSTs.",
+        tags=("paper", "mixed", "deployment"))
+
+
 # ---------------------------------------------------------------------- #
 # the catalog
 # ---------------------------------------------------------------------- #

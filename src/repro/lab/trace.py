@@ -42,7 +42,7 @@ def load_spec_from_report(path: str, fp: str) -> ScenarioSpec:
 
 def trace_scenario(spec: ScenarioSpec, model, seconds: float = 10.0,
                    interval: float = 0.5, config: TraceConfig | None = None,
-                   seg_backend: str = "jax") -> RunTrace:
+                   seg_backend: str = "auto") -> RunTrace:
     """Run ``spec`` DIAL-tuned through the traced fused loop and return
     the normalized :class:`RunTrace` (fleet columns = the scenario's
     interfaces, one OST track each)."""

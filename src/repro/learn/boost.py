@@ -38,8 +38,8 @@ import functools
 
 import numpy as np
 
-from repro.core.gbdt import (GAIN_DECIMALS, DenseForest, GBDTParams,
-                             bin_codes, quantile_edges)
+from repro.core.gbdt import (DenseForest, GBDTParams, bin_codes,
+                             quantile_edges, quantize_gains)
 
 _INT32_MAX = np.iinfo(np.int32).max
 
@@ -166,7 +166,7 @@ def _grow_forest(Xb, edges_pad, bin_count, y, valid, masks, perm, bnd,
 
     ``precision="exact"`` (float64 under ``enable_x64``) replicates the
     numpy trainer split for split, including its quantized tie-breaking
-    and float32-threshold partition quirks; ``"fast"`` runs everything
+    and float32-threshold partition quirk; ``"fast"`` runs everything
     in float32 and skips the quirk emulation — statistically equivalent
     forests (AUC-parity tested) at half the memory traffic, the
     production choice for online refits.
@@ -243,8 +243,7 @@ def _grow_forest(Xb, edges_pad, bin_count, y, valid, masks, perm, bnd,
         gh2 = jnp.stack([g, h])                      # (2, n)
         hist_fn = make_hist(gh2)
 
-        node = jnp.zeros(n, dtype=jnp.int32)         # build partition
-        mnode = jnp.zeros(n, dtype=jnp.int32)        # margin partition
+        node = jnp.zeros(n, dtype=jnp.int32)
         feat_parts, thr_parts = [], []
         hist = vals = None
         for d in range(max_depth):
@@ -272,7 +271,7 @@ def _grow_forest(Xb, edges_pad, bin_count, y, valid, masks, perm, bnd,
                   & (HR >= min_child_hess))
             gain = jnp.where(ok, gain, -jnp.inf)
             if not fast:
-                gain = jnp.round(gain, GAIN_DECIMALS)  # backend-stable ties
+                gain = quantize_gains(gain)   # backend-stable ties
 
             # first-occurrence argmax over the flattened (F, NB-1) grid ==
             # the numpy trainer's lowest-feature-then-lowest-bin tie-break
@@ -302,25 +301,19 @@ def _grow_forest(Xb, edges_pad, bin_count, y, valid, masks, perm, bnd,
                 xb = jnp.take_along_axis(Xb, f_node[:, None], axis=1)[:, 0]
                 return 2 * ptr + 1 + (xb > tb_node).astype(ptr.dtype)
 
-            tb_margin = jnp.where(has_split, b_best, _INT32_MAX)
             if fast:
-                # one partition: code > b  <=>  raw x > threshold
-                node = mnode = descend(node, tb_margin)
+                # code > b  <=>  raw x > threshold
+                tb = jnp.where(has_split, b_best, _INT32_MAX)
             else:
                 # The numpy trainer keeps thresholds in float32 and
                 # recovers the partition bin with searchsorted(edges,
-                # float32(thr)): when float32 rounds the edge *up*, the
-                # build-time descend routes bin b+1 left, while the
-                # margin-update descend (raw x > float32 thr) still
-                # routes it right.  Replicate both: `node` follows the
-                # build partition (histograms, leaves), `mnode` the
-                # raw-threshold partition (margin updates).
+                # float32(thr)): when float32 rounds the edge *up*, bin
+                # b+1 goes left as well.  Histograms, leaves and the
+                # margin update all follow that one partition.
                 up = edge_val.astype(jnp.float32).astype(dt) > edge_val
-                tb_build = jnp.where(has_split,
-                                     b_best + up.astype(jnp.int32),
-                                     _INT32_MAX)
-                node = descend(node, tb_build)
-                mnode = descend(mnode, tb_margin)
+                tb = jnp.where(has_split, b_best + up.astype(jnp.int32),
+                               _INT32_MAX)
+            node = descend(node, tb)
 
         # level-order concatenation == global node ids 0, 1-2, 3-6, ...
         feature = jnp.concatenate(feat_parts)
@@ -335,7 +328,7 @@ def _grow_forest(Xb, edges_pad, bin_count, y, valid, masks, perm, bnd,
         cnt = sel @ valid
         newton = -lr * g_leaf / (h_leaf + lam)
         leaf = jnp.where(cnt > 0, newton, vals[leaf_j // 2])
-        return margin + leaf[mnode - n_internal], (feature, threshold, leaf)
+        return margin + leaf[node - n_internal], (feature, threshold, leaf)
 
     margin0 = jnp.full(n, base, dtype=dt)
     _, (features, thresholds, leaves) = jax.lax.scan(
@@ -361,9 +354,10 @@ def _make_grow_fn(max_depth: int, hist_backend: str, batched: bool,
 def _x64_ctx(precision: str):
     import contextlib
 
-    from jax.experimental import enable_x64
+    import jax
 
-    return enable_x64() if precision == "exact" else contextlib.nullcontext()
+    return (jax.enable_x64(True) if precision == "exact"
+            else contextlib.nullcontext())
 
 
 def _check_hist_backend(hist_backend: str, precision: str) -> str:

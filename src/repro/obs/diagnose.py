@@ -88,7 +88,7 @@ class DiagnoseConfig:
     loss_threshold: float = 0.05
     min_best_static_mbs: float = 1.0
     max_evidence: int = 8
-    seg_backend: str = "jax"
+    seg_backend: str = "auto"
     reproduce_frac: float = 0.02
     recover_frac: float = 0.5
 

@@ -98,21 +98,16 @@ def collect_provenance() -> dict:
         "platform": platform.platform(),
         "python": platform.python_version(),
     }
-    try:
-        import jax
-        prov["jax_version"] = jax.__version__
-        devs = jax.devices()
-        prov["device_count"] = len(devs)
-        prov["device_kind"] = devs[0].device_kind if devs else "none"
-        prov["default_backend"] = jax.default_backend()
-    except Exception:   # jax may be absent or fail to init headless
-        prov["jax_version"] = "unavailable"
-        prov["device_count"] = 0
-        prov["device_kind"] = "none"
-        prov["default_backend"] = "none"
-    try:
-        from repro.lab.batch import loop_cache_stats
-        prov["loop_cache"] = loop_cache_stats()
-    except Exception:
-        prov["loop_cache"] = {"hits": 0, "misses": 0, "size": 0}
+    # a record must name the device it ran on: a failed JAX or device
+    # init raises here instead of writing a placeholder
+    import jax
+
+    from repro.lab.batch import loop_cache_stats
+
+    devs = jax.devices()
+    prov["jax_version"] = jax.__version__
+    prov["device_count"] = len(devs)
+    prov["device_kind"] = devs[0].device_kind
+    prov["default_backend"] = jax.default_backend()
+    prov["loop_cache"] = loop_cache_stats()
     return prov

@@ -9,14 +9,14 @@ interval.  This module compiles the *whole interval* into one jitted
     (SimState', WorkloadState')
 
 with every per-OST / per-client / per-stripe reduction routed through
-the shared :mod:`repro.kernels.segment_reduce` helper — on TPU a Pallas
-one-hot-matmul kernel, elsewhere ``jax.ops.segment_sum``.
+the shared :mod:`repro.kernels.segment_reduce` helper (by default XLA's
+``jax.ops.segment_sum``, on every platform).
 
-Numerics: the scan is traced under ``enable_x64`` so arithmetic matches
-the float64 numpy oracle (the equivalence tests hold both paths to
-≤1e-6 relative error on all probe counters; in practice they agree to
-~1e-12).  The TPU Pallas segment kernel accumulates in f32 — it is only
-selected on TPU, where the oracle comparison does not run.
+Numerics: the scan is traced under ``jax.enable_x64`` so arithmetic
+matches the float64 numpy oracle (the equivalence tests hold both paths
+to ≤1e-6 relative error on all probe counters; in practice they agree
+to ~1e-12).  The Pallas segment kernel, selectable by name, accumulates
+in float32.
 """
 
 from __future__ import annotations
@@ -25,11 +25,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.kernels.segment_reduce.ops import make_segment_sum
-from repro.pfs.state import (PAGE_SIZE, READ, WRITE, Demand, Disturbance,
-                             SimParams, SimState, SimTopo)
+from repro.pfs.state import (BYTE_TOL, PAGE_SIZE, READ, TIME_TOL, WRITE,
+                             Demand, Disturbance, SimParams, SimState,
+                             SimTopo)
 from repro.pfs.workloads import WorkloadState, WorkloadTable
 
 
@@ -108,13 +108,15 @@ def engine_step_jax(params: SimParams, topo: SimTopo, state: SimState,
     for op in (READ, WRITE):
         pend = pending[op]
         room = jnp.maximum(p.max_rpc_queue - queue_rpcs[op], 0.0)
-        n_full = jnp.minimum(jnp.floor(pend / win_bytes), room)
+        n_full = jnp.minimum(jnp.floor((pend + BYTE_TOL) / win_bytes), room)
         full_bytes = n_full * win_bytes
         queue_rpcs[op] = queue_rpcs[op] + n_full
         queue_bytes[op] = queue_bytes[op] + full_bytes
         pend = pend - full_bytes
-        hold_age[op] = jnp.where(pend > 0, hold_age[op] + dt, 0.0)
-        expire = (pend > 0) & (hold_age[op] >= p.hold_time(op)) & (room > n_full)
+        partial = pend > BYTE_TOL
+        hold_age[op] = jnp.where(partial, hold_age[op] + dt, 0.0)
+        expire = (partial & (hold_age[op] >= p.hold_time(op) - TIME_TOL)
+                  & (room > n_full))
         queue_rpcs[op] = queue_rpcs[op] + expire
         queue_bytes[op] = queue_bytes[op] + jnp.where(expire, pend, 0.0)
         ctr_partial[op] = ctr_partial[op] + expire
@@ -175,7 +177,7 @@ def engine_step_jax(params: SimParams, topo: SimTopo, state: SimState,
         jnp.power(p.ost_buffer_bytes / jnp.maximum(ost_queued, 1.0),
                   p.congestion_exp),
         1.0)
-    active_transfer = jnp.where(want > 0,
+    active_transfer = jnp.where(want > BYTE_TOL,
                                 active_rpcs[READ] + active_rpcs[WRITE], 0.0)
     ost_shares = segsum(active_transfer, osc_ost, n_osts)
     share = _div_where(active_transfer, ost_shares[osc_ost],
@@ -206,7 +208,7 @@ def engine_step_jax(params: SimParams, topo: SimTopo, state: SimState,
         avg = jnp.maximum(avg_size[op], 1.0)
         done_rpcs = jnp.minimum(drained / avg, active_rpcs[op])
         inflight_bytes = unready[op] + ready_b[op]
-        done_rpcs = jnp.where(inflight_bytes <= 1e-9, active_rpcs[op],
+        done_rpcs = jnp.where(inflight_bytes <= BYTE_TOL, active_rpcs[op],
                               done_rpcs)
         prev_active = active_rpcs[op]
         active_rpcs[op] = active_rpcs[op] - done_rpcs
@@ -335,7 +337,7 @@ class FusedEngine:
         """
         if schedule is None:
             schedule = self._neutral_sched
-        with enable_x64():
+        with jax.enable_x64(True):
             jstate = jax.tree.map(jnp.asarray, state)
             jws = jax.tree.map(jnp.asarray, wstate)
             jsched = jax.tree.map(jnp.asarray, schedule)

@@ -56,8 +56,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.config_space import SPACE, ConfigSpace
@@ -212,7 +210,7 @@ def conditional_score_greedy_jnp(probs, ops, current,
     the scalar and the batched numpy oracles on adversarial rows.
     """
     params = params if params is not None else TunerParams()
-    with enable_x64():
+    with jax.enable_x64(True):
         out = score_greedy_arrays(
             jnp.asarray(probs, dtype=jnp.float64),
             jnp.asarray(ops),
@@ -360,7 +358,8 @@ class FusedLoop:
         if self.tuned:
             feature, threshold, leaf, base, depth, n_features = \
                 model.paired_arrays()
-            with enable_x64():   # constants must keep f64 (oracle parity)
+            # constants must keep f64 (oracle parity)
+            with jax.enable_x64(True):
                 feature = jnp.asarray(feature)
                 threshold = jnp.asarray(threshold)
                 leaf = jnp.asarray(leaf)
@@ -549,8 +548,13 @@ class FusedLoop:
                         ys["timeline"] = taps
                 return (state, wstate, cur, hist, tick), ys
 
-            carry0 = (state, wstate, probe_state(state), hist0,
-                      jnp.asarray(0, dtype=jnp.int64))
+            tick0 = jnp.asarray(0, dtype=jnp.int64)
+            if self.mesh is not None:
+                # run constants join a carry that varies over the fleet
+                # axis; shard_map's type check needs them marked so
+                hist0, tick0 = jax.lax.pcast(
+                    (hist0, tick0), self.mesh.axis_names[0], to="varying")
+            carry0 = (state, wstate, probe_state(state), hist0, tick0)
             (state, wstate, _, hist, _), trace = jax.lax.scan(
                 interval, carry0, sched)
             return state, wstate, trace, hist
@@ -581,10 +585,24 @@ class FusedLoop:
                 # so each shard is an independent fleet slice — the
                 # paper's decentralization, literal in the partitioning.
                 spec = PartitionSpec(self.mesh.axis_names[0])
-                fn = shard_map(fn, mesh=self.mesh,
+                fn = jax.shard_map(fn, mesh=self.mesh,
                                in_specs=(spec,) * n_args, out_specs=spec)
             self._jits[n_args] = jax.jit(fn, donate_argnums=(1, 2))
         return self._jits[n_args]
+
+    def lower(self, *args):
+        """Lower the jitted run without running it.
+
+        ``args`` are :meth:`run`'s device-side arguments ``(table, state,
+        wstate, schedule, [tune_mask, [intervention]])`` — arrays or
+        ``jax.ShapeDtypeStruct``s, the schedule already shaped per
+        interval, batched and sharded as the loop expects.  Returns the
+        ``jax.stages.Lowered``; ``.compile()`` on it shows what the
+        target's compiler accepts and emits (kernels, collectives,
+        memory) without a dispatch.
+        """
+        with jax.enable_x64(True):
+            return self._get_run(len(args)).lower(*args)
 
     # ------------------------------------------------------------------ #
     def run_trace(self, result: "FusedLoopResult"):
@@ -680,7 +698,7 @@ class FusedLoop:
                         intervene)
                 args = args + (intervene,)
 
-        with enable_x64():
+        with jax.enable_x64(True):
             if self.mesh is not None:
                 # place inputs *pre-sharded*: jit then donates the
                 # caller's buffers directly instead of donating a

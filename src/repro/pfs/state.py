@@ -29,6 +29,18 @@ import numpy as np
 
 PAGE_SIZE = 4096  # bytes, Linux page
 
+# The discrete steps of a tick (whole-window RPC packing, partial-RPC
+# expiry, "nothing left in flight", a reader's next request) compare
+# float64 byte and time quantities against boundaries they often reach
+# exactly.  Backends round differently: XLA's CPU backend fuses
+# multiply-adds, and the TPU emulates float64 without correct rounding
+# (~1e-15 relative per op).  A one-ulp difference at such a boundary
+# would flip the step and drift the counters by percents, so each step
+# compares with a tolerance far above rounding noise and far below
+# anything physical: half a byte, one nanosecond.
+BYTE_TOL = 0.5
+TIME_TOL = 1e-9
+
 # Operation codes.
 READ = 0
 WRITE = 1
@@ -394,13 +406,15 @@ def engine_step(params: SimParams, topo: SimTopo, state: SimState,
     for op in (READ, WRITE):
         pend = s.pending[op]
         room = np.maximum(p.max_rpc_queue - s.queue_rpcs[op], 0.0)
-        n_full = np.minimum(np.floor(pend / win_bytes), room)
+        n_full = np.minimum(np.floor((pend + BYTE_TOL) / win_bytes), room)
         full_bytes = n_full * win_bytes
         s.queue_rpcs[op] += n_full
         s.queue_bytes[op] += full_bytes
         pend = pend - full_bytes
-        s.hold_age[op] = np.where(pend > 0, s.hold_age[op] + dt, 0.0)
-        expire = (pend > 0) & (s.hold_age[op] >= p.hold_time(op)) & (room > n_full)
+        partial = pend > BYTE_TOL
+        s.hold_age[op] = np.where(partial, s.hold_age[op] + dt, 0.0)
+        expire = (partial & (s.hold_age[op] >= p.hold_time(op) - TIME_TOL)
+                  & (room > n_full))
         s.queue_rpcs[op] += expire
         s.queue_bytes[op] += np.where(expire, pend, 0.0)
         s.ctr_partial_rpcs[op] += expire
@@ -480,7 +494,7 @@ def engine_step(params: SimParams, topo: SimTopo, state: SimState,
                  p.congestion_exp),
         1.0,
     )
-    active_transfer = np.where(want > 0,
+    active_transfer = np.where(want > BYTE_TOL,
                                s.active_rpcs[READ] + s.active_rpcs[WRITE], 0.0)
     ost_shares = np.bincount(osc_ost, weights=active_transfer,
                              minlength=n_osts)
@@ -522,7 +536,8 @@ def engine_step(params: SimParams, topo: SimTopo, state: SimState,
         avg = np.maximum(s.active_avg_size[op], 1.0)
         done_rpcs = np.minimum(np.divide(drained, avg), s.active_rpcs[op])
         inflight_bytes = s.unready_bytes[op] + s.ready_bytes[op]
-        done_rpcs = np.where(inflight_bytes <= 1e-9, s.active_rpcs[op], done_rpcs)
+        done_rpcs = np.where(inflight_bytes <= BYTE_TOL, s.active_rpcs[op],
+                             done_rpcs)
         prev_active = s.active_rpcs[op].copy()
         s.active_rpcs[op] -= done_rpcs
         s.ctr_rpcs_done[op] += done_rpcs

@@ -23,7 +23,8 @@ import numpy as np
 
 from repro.kernels.segment_reduce.ops import segment_sum_np as _np_segment_sum
 from repro.pfs.engine import READ, WRITE
-from repro.pfs.state import Demand, SimParams, SimState, SimTopo
+from repro.pfs.state import (BYTE_TOL, Demand, SimParams, SimState,
+                             SimTopo)
 
 
 @dataclasses.dataclass
@@ -62,7 +63,10 @@ class Workload:
     def _active(self, sim) -> bool:
         if self.duty_cycle >= 1.0:
             return True
-        return (sim.now % self.period) < self.duty_cycle * self.period
+        # the tick's midpoint decides, so a tick that starts on a period
+        # boundary is on whichever way ``now`` rounded
+        return ((sim.now + 0.5 * sim.params.tick) % self.period
+                < self.duty_cycle * self.period)
 
     def done_bytes(self, sim) -> float:
         return float(sim.ctr_bytes_done[self.op, self._osc_ids].sum()) - self._done_base
@@ -81,7 +85,7 @@ class Workload:
             want = depth - outstanding
             # a thread can re-issue at most thread_rate anyway
             want = min(max(want, 0.0), self.n_threads * self.thread_rate * dt)
-            if want <= 0:
+            if want <= BYTE_TOL:
                 return
             self._issue(sim, want)
         else:
@@ -398,7 +402,8 @@ class WorkloadTable:
         active = xp.logical_and(
             xp.logical_or(
                 self.duty_cycle >= 1.0,
-                xp.mod(now, self.period) < self.duty_cycle * self.period),
+                xp.mod(now + 0.5 * dt, self.period)
+                < self.duty_cycle * self.period),
             self.row_valid)
         cap_row = self.n_threads * self.thread_rate * dt
         # wave-invariant reader inputs: reads never observe intra-tick
@@ -416,7 +421,8 @@ class WorkloadTable:
             is_r = xp.logical_and(xp.logical_and(in_wave, self.op == READ),
                                   active)
             want_r = xp.clip(depth - (issued - done_row), 0.0, cap_row)
-            want_r = xp.where(xp.logical_and(is_r, want_r > 0), want_r, 0.0)
+            want_r = xp.where(xp.logical_and(is_r, want_r > BYTE_TOL),
+                              want_r, 0.0)
             issued = issued + want_r
             per_e = want_r[e_row] / slen_e
             pend_read_add = pend_read_add + segsum(per_e, e_osc, n)

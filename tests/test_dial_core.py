@@ -127,3 +127,32 @@ def test_agent_only_two_snapshots_in_memory(dial_model):
         agent.tick()
     for osc, hist in agent._hist.items():
         assert len(hist) <= 2
+
+
+def test_log2_knob_jnp_matches_numpy_bitwise():
+    """The jitted knob log2 (compares only, no 64-bit bitcast) equals
+    np.log2 bit for bit on every Θ value and on the powers of two up to
+    2^20; any 1-ulp slip flips GBDT splits at threshold 0."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.core.metrics import _log2_knob
+
+    vals = np.unique(np.concatenate([
+        np.asarray(SPACE.window_pages, dtype=np.int64),
+        np.asarray(SPACE.rpcs_in_flight, dtype=np.int64),
+        2 ** np.arange(21, dtype=np.int64)]))
+    want = np.log2(vals.astype(np.float64))
+    with jax.enable_x64(True):
+        got = np.asarray(jax.jit(lambda x: _log2_knob(x, jnp))(vals))
+        got_f = np.asarray(jax.jit(lambda x: _log2_knob(x, jnp))(
+            vals.astype(np.float64)))
+    assert got.dtype == np.float64
+    np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))
+    np.testing.assert_array_equal(got_f.view(np.int64), want.view(np.int64))
+    # off-grid knobs fall back to the backend log2
+    with jax.enable_x64(True):
+        odd = np.asarray(jax.jit(lambda x: _log2_knob(x, jnp))(
+            np.array([3.0, 48.0, 1000.0])))
+    np.testing.assert_allclose(odd, np.log2([3.0, 48.0, 1000.0]),
+                               rtol=1e-15)

@@ -223,3 +223,69 @@ def test_segment_sum_kernel_matches_refs(e, s, block):
                                    block_e=block, interpret=True))
     np.testing.assert_allclose(got_ref, want, rtol=1e-5, atol=1e-4)
     np.testing.assert_allclose(got_pal, want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("n_osc,n_ost", [(512, 8), (2048, 16)])
+def test_segment_sum_f64_path_matches_numpy(n_osc, n_ost):
+    """The segment reduction the engine calls under x64, through the
+    Pallas kernel's float32 cast: float64 in, float64 out, within the
+    engine-equivalence bound of the numpy oracle at engine widths."""
+    jax = pytest.importorskip("jax")
+    from repro.kernels.segment_reduce.ops import (make_segment_sum,
+                                                  segment_sum_np)
+
+    segsum = make_segment_sum("pallas_interpret")
+    rng = np.random.default_rng(n_osc)
+    osc_ost = np.arange(n_osc) % n_ost
+    for scale in (1.0, 1e9):          # unit fractions and byte counters
+        x = rng.random(n_osc) * scale * (rng.random(n_osc) < 0.7)
+        with jax.enable_x64(True):
+            got = np.asarray(jax.jit(lambda v, i: segsum(v, i, n_ost))(
+                x, osc_ost))
+        assert got.dtype == np.float64
+        np.testing.assert_allclose(got, segment_sum_np(x, osc_ost, n_ost),
+                                   rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["vpic_checkpoint", "filebench_mix",
+                                  "dlio_bert", "client_eviction"])
+def test_engine_steps_hold_under_rounding_noise(name):
+    """A stand-in for a backend that rounds float64 differently (XLA's
+    CPU FMAs, the TPU's emulated f64): every float state field is
+    multiplied by (1 + 1e-13 * N(0, 1)) after every tick.  The engine's
+    discrete steps (window packing, partial expiry, completion, a
+    reader's next request, duty cycles) must not flip, so the counters
+    stay within 1e-6 of the clean run."""
+    from repro.lab.scenarios import build, get_scenario
+    from repro.pfs.state import _STATE_FIELDS, engine_step
+
+    rng = np.random.default_rng(0)
+    n_ticks = 2000                      # 10 s at the 5 ms tick
+
+    def noisy(a):
+        a = np.asarray(a)
+        if a.dtype.kind != "f":
+            return a
+        return a * (1.0 + 1e-13 * rng.standard_normal(a.shape))
+
+    def run(noise):
+        b = build(get_scenario(name))
+        state, wstate = b.state, b.wstate
+        sched = b.schedule(0, n_ticks)
+        for t in range(n_ticks):
+            demand, wstate = b.table.demand_step(b.params, wstate, state)
+            state = engine_step(b.params, b.topo, state, demand,
+                                disturbance=sched.at_tick(t))
+            if noise:
+                for f in _STATE_FIELDS:
+                    if f != "tick_index":
+                        setattr(state, f, noisy(getattr(state, f)))
+                state.now = float(noisy(state.now))
+                wstate.issued = noisy(wstate.issued)
+        return state
+
+    clean, jittered = run(False), run(True)
+    assert clean.ctr_bytes_done.sum() > 0
+    for f in PROBE_COUNTERS:
+        np.testing.assert_allclose(getattr(jittered, f), getattr(clean, f),
+                                   rtol=1e-6, atol=1e-3, err_msg=f)

@@ -203,14 +203,14 @@ def test_fleet_jax_backend_matches_numpy_decisions(dial_model):
 
 
 # ---------------------------------------------------------------------- #
-# paired-forest kernel vs refs
+# paired-forest traversal vs the split numpy forests
 # ---------------------------------------------------------------------- #
 def test_paired_forest_kernel_matches_split_forests():
     import jax.numpy as jnp
 
     from repro.core.gbdt import GBDTClassifier, GBDTParams
-    from repro.kernels.gbdt_forest.kernel import paired_forest_margin
-    from repro.kernels.gbdt_forest.ops import pair_forests
+    from repro.kernels.gbdt_forest.ops import (make_fleet_predictor,
+                                               pair_forests)
     from repro.kernels.gbdt_forest.ref import paired_forest_margin_ref
 
     rng = np.random.default_rng(0)
@@ -233,9 +233,13 @@ def test_paired_forest_kernel_matches_split_forests():
     args = (jnp.asarray(x), jnp.asarray(op), jnp.asarray(feature),
             jnp.asarray(threshold), jnp.asarray(leaf), jnp.asarray(base))
     ref = np.asarray(paired_forest_margin_ref(*args, depth))
-    pal = np.asarray(paired_forest_margin(*args, depth, block_n=64))
-    np.testing.assert_allclose(ref, pal, rtol=1e-5, atol=1e-5)
-    # and against the unpadded numpy oracles
+    # against the unpadded numpy oracles
     want = np.where(op == 0, fr.predict_margin(x[:, :10]),
                     fw.predict_margin(x[:, :14]))
     np.testing.assert_allclose(ref, want, rtol=1e-4, atol=1e-4)
+    # and the fleet predictor's split read/write batches
+    p_r, p_w = make_fleet_predictor(fr, fw)(xr[op == 0], xw[op == 1])
+    np.testing.assert_allclose(p_r, fr.predict_proba(xr[op == 0]),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(p_w, fw.predict_proba(xw[op == 1]),
+                               rtol=1e-4, atol=1e-4)

@@ -41,7 +41,9 @@ def test_collective_parser_multiplies_loop_trips():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.utils import hlo as H
-mesh = jax.make_mesh((4,), ("data",))
+# auto axes: the partitioner, not the type system, places the psum
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.ShapeDtypeStruct((8, 64), jnp.float32,
                          sharding=NamedSharding(mesh, P(None, "data")))
 def f(x):

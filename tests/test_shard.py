@@ -221,7 +221,6 @@ def test_donation_consumes_state_buffers_8dev():
     peak memory); the un-donated table stays alive."""
     out = run_py(PRELUDE + """
 import jax
-from jax.experimental import enable_x64
 from repro.distributed.sharding import fleet_mesh, fleet_sharding
 from repro.lab.batch import stack_scenarios
 from repro.lab.scenarios import SCENARIOS, build, variants
@@ -233,7 +232,7 @@ batch = stack_scenarios(
 loop = FusedLoop(batch.params, batch.topo, 20, model, seg_backend="jax",
                  batched=True, mesh=mesh)
 sched = loop._shape_schedule(batch.schedule(0, 2 * 20), 2)
-with enable_x64():
+with jax.enable_x64(True):
     sh = fleet_sharding(mesh)
     jargs = jax.tree.map(lambda a: jax.device_put(np.asarray(a), sh),
                          (batch.table, batch.state, batch.wstate, sched,
@@ -255,7 +254,6 @@ def test_donation_consumes_state_buffers_single_device():
     """The unsharded jit path donates too (default device placement)."""
     out = run_py(PRELUDE + """
 import jax
-from jax.experimental import enable_x64
 import jax.numpy as jnp
 from repro.lab.batch import stack_scenarios
 from repro.lab.scenarios import SCENARIOS, build, variants
@@ -266,7 +264,7 @@ batch = stack_scenarios(
 loop = FusedLoop(batch.params, batch.topo, 20, model, seg_backend="jax",
                  batched=True)
 sched = loop._shape_schedule(batch.schedule(0, 2 * 20), 2)
-with enable_x64():
+with jax.enable_x64(True):
     jargs = jax.tree.map(jnp.asarray,
                          (batch.table, batch.state, batch.wstate, sched,
                           np.ones((4, batch.n_osc), dtype=bool)))
