@@ -160,7 +160,7 @@ def main(argv=None) -> None:
              "max_fleet_seconds": probe["seconds"]})
 
     if args.json:
-        from repro.obs.timers import collect_provenance
+        from repro.obs.provenance import collect_provenance
 
         payload = {
             "schema": "dial-bench-v1",

@@ -14,6 +14,11 @@ backend      implementation
 ``auto``     jax, on every platform
 ==========  ============================================================
 
+Every jax backend runs under the ``dial.segsum`` named scope, so each
+instruction it compiles to carries that scope in its metadata and a
+profile charges its device time to the segment reductions whatever they
+compile to (``numpy`` is not traced and stays bare).
+
 ``auto`` picks XLA's scatter-add on the TPU too.  The engine runs under
 x64, and a Mosaic call cannot take float64 operands, so the kernel casts
 to float32 around the call.  Its sums then miss the engine's oracle: on
@@ -47,12 +52,23 @@ def make_segment_sum(backend: str = "auto"):
         return segment_sum_np
     if backend in ("auto", "jax"):
         from repro.kernels.segment_reduce import ref as _ref
-        return _ref.segment_sum_ref
+        return _scoped(_ref.segment_sum_ref)
     if backend in ("pallas", "pallas_interpret"):
         from repro.kernels.segment_reduce import kernel as _kernel
         interpret = backend == "pallas_interpret"
-        return functools.partial(_kernel.segment_sum, interpret=interpret)
+        return _scoped(functools.partial(_kernel.segment_sum,
+                                         interpret=interpret))
     raise ValueError(f"unknown segment_sum backend {backend!r}")
+
+
+def _scoped(fn):
+    """``fn`` under the ``dial.segsum`` named scope."""
+    import jax
+
+    def segsum(values, segment_ids, num_segments: int):
+        with jax.named_scope("dial.segsum"):
+            return fn(values, segment_ids, num_segments)
+    return segsum
 
 
 def segment_sum(values, segment_ids, num_segments: int,

@@ -44,6 +44,7 @@ from repro.core.fleet import FleetAgent
 from repro.core.tuner import TunerParams
 from repro.kernels.segment_reduce.ops import make_segment_sum
 from repro.lab.scenarios import BuiltScenario, make_schedule
+from repro.obs.spans import span
 from repro.pfs.engine_jax import engine_step_jax
 from repro.pfs.state import (_STATE_FIELDS, Disturbance, SimParams, SimState,
                              SimTopo, init_state)
@@ -109,6 +110,7 @@ class ScenarioBatch:
                 "total_interfaces": int(total),
                 "pad_waste": float(1.0 - real / total) if total else 0.0}
 
+    @span("dial.lab.schedule")
     def schedule(self, t0_tick: int, n_ticks: int) -> Disturbance:
         """Stacked ``(B, n_ticks, ...)`` disturbance schedule for one
         interval (neutral for elements without events / without specs)."""
@@ -250,6 +252,7 @@ def pad_scenario(b: BuiltScenario, cls: tuple) -> BuiltScenario:
                          table=table, state=state, wstate=wstate)
 
 
+@span("dial.lab.stack")
 def stack_scenarios(built: list[BuiltScenario],
                     ragged: bool = True) -> ScenarioBatch:
     """Stack built scenarios into one batch.
@@ -600,6 +603,7 @@ def reset_loop_cache_stats() -> None:
     _CACHE_STATS["hits"] = _CACHE_STATS["misses"] = 0
 
 
+@span("dial.lab.loop_cache")
 def _cached_loop(params, topo, steps, model, tuner_params, seg_backend,
                  tuned: bool, mesh=None, trace=None):
     from repro.pfs.loop_jax import FusedLoop
@@ -629,7 +633,6 @@ def _cached_loop(params, topo, steps, model, tuner_params, seg_backend,
             mesh=mesh, trace=trace), model)
     else:
         _CACHE_STATS["hits"] += 1
-        _FUSED_LOOPS[key][0].timers.add("loop_cache_hit", 0.0)
     return _FUSED_LOOPS[key][0]
 
 
@@ -688,15 +691,16 @@ def _run_batch_fused(batch: ScenarioBatch, model, steps: int,
         out[u_idx] = a_u
         return out
 
-    state = jax.tree.map(merge, res_t.state, res_u.state)
-    wstate = jax.tree.map(merge, res_t.wstate, res_u.wstate)
-    # decision columns come back indexed within the tuned sub-batch;
-    # remap to the caller's element order (b * n + osc fleet columns) —
-    # and merge the trace to the same order so both views agree.
-    for r in res_t.decisions:
-        r.oscs = t_idx[r.oscs // n] * n + r.oscs % n
-    merged_trace = _merge_split_trace(res_t.trace, res_u.trace, b, t_idx,
-                                      u_idx, state)
+    with span("dial.lab.merge"):
+        state = jax.tree.map(merge, res_t.state, res_u.state)
+        wstate = jax.tree.map(merge, res_t.wstate, res_u.wstate)
+        # decision columns come back indexed within the tuned sub-batch;
+        # remap to the caller's element order (b * n + osc fleet columns)
+        # — and merge the trace to the same order so both views agree.
+        for r in res_t.decisions:
+            r.oscs = t_idx[r.oscs // n] * n + r.oscs % n
+        merged_trace = _merge_split_trace(res_t.trace, res_u.trace, b,
+                                          t_idx, u_idx, state)
     batch.state, batch.wstate = state, wstate
     return _dc.replace(res_t, state=state, wstate=wstate,
                        trace=merged_trace, hist=None)

@@ -4,7 +4,8 @@ Opt-in tracing threaded through both execution paths — decision
 provenance and per-OST timelines accumulated as scan outputs inside the
 fused loop (no host callbacks), mirrored record-for-record by the host
 agent path — plus host-side sinks (JSONL, Chrome ``trace_event``,
-markdown), phase timers, and bench provenance.  See
+markdown), host spans with compile accounting (``obs/spans.py``), and
+bench provenance.  See
 ``docs/OBSERVABILITY.md``.
 """
 
@@ -19,8 +20,8 @@ from repro.obs.schema import (DECISION_FIELDS, TIMELINE_FIELDS,
 from repro.obs.sinks import (chrome_trace, read_jsonl,
                              read_jsonl_diagnosis, render_summary,
                              write_chrome, write_jsonl)
-from repro.obs.timers import (PhaseTimers, collect_provenance,
-                              compile_execute_split)
+from repro.obs.provenance import collect_provenance
+from repro.obs.spans import since, span, totals
 
 __all__ = [
     "TRACE_SCHEMA", "DECISION_FIELDS", "TIMELINE_FIELDS",
@@ -29,5 +30,5 @@ __all__ = [
     "write_chrome", "render_summary",
     "DIAGNOSIS_SCHEMA", "CAUSES", "ARMS", "DiagnoseConfig", "diagnose",
     "cause_counts", "write_diagnosis_report", "render_diagnosis_markdown",
-    "PhaseTimers", "compile_execute_split", "collect_provenance",
+    "span", "totals", "since", "collect_provenance",
 ]

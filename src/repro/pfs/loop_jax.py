@@ -61,7 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.core.config_space import SPACE, ConfigSpace
 from repro.distributed.sharding import pad_fleet, unpad_fleet
 from repro.obs.schema import TraceConfig, timeline_tap
-from repro.obs.timers import PhaseTimers
+from repro.obs.spans import span
 from repro.core.metrics import (N_READ, N_WRITE, READ_KNOB_IDX,
                                 WRITE_KNOB_IDX, snapshot_arrays)
 from repro.core.model import DIALModel
@@ -334,7 +334,6 @@ class FusedLoop:
         # literally nothing); a TraceConfig adds scan *outputs* only —
         # the decision arithmetic is shared, never forked
         self.trace_config = trace
-        self.timers = PhaseTimers()
         if mesh is not None and not self.batched:
             raise ValueError("mesh sharding needs batched=True — the "
                              "fleet axis being sharded *is* the batch "
@@ -401,10 +400,12 @@ class FusedLoop:
         def tick_body(table):
             def body(carry, dist):
                 st, ws = carry
-                demand, ws = table.demand_step(pfsp, ws, st,
-                                               xp=jnp, segsum=segsum)
-                st = engine_step_jax(pfsp, pfst, st, demand, segsum,
-                                     disturbance=dist)
+                with jax.named_scope("dial.demand"):
+                    demand, ws = table.demand_step(pfsp, ws, st,
+                                                   xp=jnp, segsum=segsum)
+                with jax.named_scope("dial.engine"):
+                    st = engine_step_jax(pfsp, pfst, st, demand, segsum,
+                                         disturbance=dist)
                 return (st, ws), None
             return body
 
@@ -417,7 +418,12 @@ class FusedLoop:
             :func:`timeline_tap` per chunk boundary as scan output (so
             the tap compute is paid once per ``stride`` ticks, not per
             tick, and vmap/shard_map stack it like any other ys).
+            Every branch runs under the ``dial.tick`` scope.
             """
+            with jax.named_scope("dial.tick"):
+                return _run_ticks(table, state, wstate, dist)
+
+        def _run_ticks(table, state, wstate, dist):
             body = tick_body(table)
             if not tap_timeline:
                 (state, wstate), _ = jax.lax.scan(
@@ -471,81 +477,92 @@ class FusedLoop:
                 state, wstate, prev, hist, tick = carry
                 state, wstate, taps = run_ticks(table, state, wstate, dist)
 
-                # probe + snapshot: the oracle arithmetic, on device
-                cur = probe_state(state)
-                _, snap_r, snap_w, vol_r, vol_w = snapshot_arrays(
-                    prev, cur, xp=jnp)
-                hr, hw, hrv, hwv = hist
-                hist = (jnp.concatenate([hr[1:], snap_r[None]]),
-                        jnp.concatenate([hw[1:], snap_w[None]]),
-                        jnp.concatenate([hrv[1:], vol_r[None]]),
-                        jnp.concatenate([hwv[1:], vol_w[None]]))
-                hr, hw, hrv, hwv = hist
-                tick = tick + 1
+                # the decision step, from probe to knob write-back
+                with jax.named_scope("dial.decide"):
+                    # probe + snapshot: the oracle arithmetic, on device
+                    cur = probe_state(state)
+                    _, snap_r, snap_w, vol_r, vol_w = snapshot_arrays(
+                        prev, cur, xp=jnp)
+                    hr, hw, hrv, hwv = hist
+                    hist = (jnp.concatenate([hr[1:], snap_r[None]]),
+                            jnp.concatenate([hw[1:], snap_w[None]]),
+                            jnp.concatenate([hrv[1:], vol_r[None]]),
+                            jnp.concatenate([hwv[1:], vol_w[None]]))
+                    hr, hw, hrv, hwv = hist
+                    tick = tick + 1
 
-                # gating masks (same predicates as FleetAgent.tick)
-                ops = jnp.where(vol_r >= vol_w, READ, WRITE)
-                active = jnp.maximum(vol_r, vol_w) >= self.min_volume
-                v0 = jnp.where(ops == READ, hrv[0], hwv[0])
-                v1 = jnp.where(ops == READ, vol_r, vol_w)
-                ratio = v1 / jnp.maximum(v0, 1.0)
-                steady = (ratio >= 0.5) & (ratio <= 2.0)
-                warm = tick >= warm_from
-                # interventions (iv) are a trace-time branch: iv=None
-                # compiles the exact unintervened graph, and the
-                # neutral intervention is an arithmetic identity (all
-                # masks False) — counterfactual replays stay diffable
-                # row-for-row against the factual run
-                gate_ok = active & steady
-                if iv is not None:
-                    gate_ok = gate_ok | iv.force_gates
-                decide = gate_ok & warm & tune_mask
+                    # gating masks (same predicates as FleetAgent.tick)
+                    ops = jnp.where(vol_r >= vol_w, READ, WRITE)
+                    active = jnp.maximum(vol_r, vol_w) >= self.min_volume
+                    v0 = jnp.where(ops == READ, hrv[0], hwv[0])
+                    v1 = jnp.where(ops == READ, vol_r, vol_w)
+                    ratio = v1 / jnp.maximum(v0, 1.0)
+                    steady = (ratio >= 0.5) & (ratio <= 2.0)
+                    warm = tick >= warm_from
+                    # interventions (iv) are a trace-time branch: iv=None
+                    # compiles the exact unintervened graph, and the
+                    # neutral intervention is an arithmetic identity (all
+                    # masks False) — counterfactual replays stay diffable
+                    # row-for-row against the factual run
+                    gate_ok = active & steady
+                    if iv is not None:
+                        gate_ok = gate_ok | iv.force_gates
+                    decide = gate_ok & warm & tune_mask
 
-                # features + one fused paired-forest pass for all rows
-                x_r = features(hr, N_READ, READ_KNOB_IDX)
-                x_w = features(hw, N_WRITE, WRITE_KNOB_IDX)
-                x_r = jnp.pad(x_r, ((0, 0), (0, n_features - dim_r)))
-                x_w = jnp.pad(x_w, ((0, 0), (0, n_features - dim_w)))
-                op_rows = jnp.repeat(ops, m)
-                x = jnp.where((op_rows == READ)[:, None], x_r, x_w)
-                margin = paired_forest_margin_ref(
-                    x, op_rows, feature, threshold, leaf, base, depth)
-                p32 = 1.0 / (1.0 + jnp.exp(-jnp.clip(margin, -30.0, 30.0)))
-                probs = p32.astype(jnp.float64).reshape(n, m)
+                    # features + one fused paired-forest pass for all rows
+                    with jax.named_scope("dial.features"):
+                        x_r = features(hr, N_READ, READ_KNOB_IDX)
+                        x_w = features(hw, N_WRITE, WRITE_KNOB_IDX)
+                        x_r = jnp.pad(x_r, ((0, 0), (0, n_features - dim_r)))
+                        x_w = jnp.pad(x_w, ((0, 0), (0, n_features - dim_w)))
+                        op_rows = jnp.repeat(ops, m)
+                        x = jnp.where((op_rows == READ)[:, None], x_r, x_w)
+                    with jax.named_scope("dial.forest"):
+                        margin = paired_forest_margin_ref(
+                            x, op_rows, feature, threshold, leaf, base,
+                            depth)
+                        p32 = 1.0 / (1.0 + jnp.exp(
+                            -jnp.clip(margin, -30.0, 30.0)))
+                        probs = p32.astype(jnp.float64).reshape(n, m)
 
-                # Algorithm 1 (the oracle reductions) + knob write-back;
-                # `current` comes from the probe itself, never a shadow
-                cur_theta = jnp.stack([state.window_pages,
-                                       state.rpcs_in_flight], axis=1)
-                theta, changed, n_cand, score = score_greedy_arrays(
-                    probs, ops, cur_theta, theta_raw, tp, xp=jnp)
-                apply = decide & changed
-                if iv is not None:
-                    apply = apply & ~iv.freeze
-                new_wp = jnp.where(apply, theta[:, 0], state.window_pages)
-                new_rf = jnp.where(apply, theta[:, 1],
-                                   state.rpcs_in_flight)
-                if iv is not None:
-                    new_wp = jnp.where(iv.pin_mask, iv.pin_theta[:, 0],
-                                       new_wp)
-                    new_rf = jnp.where(iv.pin_mask, iv.pin_theta[:, 1],
-                                       new_rf)
-                state = dataclasses.replace(
-                    state, window_pages=new_wp, rpcs_in_flight=new_rf)
+                    # Algorithm 1 (the oracle reductions) + knob
+                    # write-back; `current` comes from the probe itself,
+                    # never a shadow
+                    with jax.named_scope("dial.alg1"):
+                        cur_theta = jnp.stack([state.window_pages,
+                                               state.rpcs_in_flight], axis=1)
+                        theta, changed, n_cand, score = score_greedy_arrays(
+                            probs, ops, cur_theta, theta_raw, tp, xp=jnp)
+                        apply = decide & changed
+                        if iv is not None:
+                            apply = apply & ~iv.freeze
+                        new_wp = jnp.where(apply, theta[:, 0],
+                                           state.window_pages)
+                        new_rf = jnp.where(apply, theta[:, 1],
+                                           state.rpcs_in_flight)
+                        if iv is not None:
+                            new_wp = jnp.where(iv.pin_mask,
+                                               iv.pin_theta[:, 0], new_wp)
+                            new_rf = jnp.where(iv.pin_mask,
+                                               iv.pin_theta[:, 1], new_rf)
+                        state = dataclasses.replace(
+                            state, window_pages=new_wp,
+                            rpcs_in_flight=new_rf)
 
-                ys = {"decided": decide, "ops": ops, "theta": theta,
-                      "changed": changed, "n_candidates": n_cand,
-                      "score": score, "probs": probs}
-                if tcfg is not None:
-                    # provenance extras: every value already exists in
-                    # the decision graph — tracing adds outputs, never
-                    # arithmetic (bit-neutrality, tests/test_obs.py)
-                    ys.update({"t": state.now, "vol_r": vol_r,
-                               "vol_w": vol_w, "active": active,
-                               "steady": steady, "warm": warm,
-                               "ratio": ratio, "cur_theta": cur_theta})
-                    if taps is not None:
-                        ys["timeline"] = taps
+                    ys = {"decided": decide, "ops": ops, "theta": theta,
+                          "changed": changed, "n_candidates": n_cand,
+                          "score": score, "probs": probs}
+                    if tcfg is not None:
+                        # provenance extras: every value already exists
+                        # in the decision graph — tracing adds outputs,
+                        # never arithmetic (bit-neutrality,
+                        # tests/test_obs.py)
+                        ys.update({"t": state.now, "vol_r": vol_r,
+                                   "vol_w": vol_w, "active": active,
+                                   "steady": steady, "warm": warm,
+                                   "ratio": ratio, "cur_theta": cur_theta})
+                        if taps is not None:
+                            ys["timeline"] = taps
                 return (state, wstate, cur, hist, tick), ys
 
             tick0 = jnp.asarray(0, dtype=jnp.int64)
@@ -631,32 +648,10 @@ class FusedLoop:
                              + a.shape[t_ax + 1:])
         return jax.tree.map(reshape, sched)
 
-    def run(self, table: WorkloadTable, state: SimState,
-            wstate: WorkloadState, n_intervals: int,
-            schedule: Disturbance | None = None,
-            tune_mask: np.ndarray | None = None,
-            intervene: "Intervention | None" = None) -> FusedLoopResult:
-        """Advance ``n_intervals`` of engine + tuning in one dispatch.
-
-        ``schedule`` is a whole-run :class:`Disturbance` with a flat
-        leading ``(n_intervals * steps, ...)`` time axis (batched: a
-        ``(B, total_ticks, ...)`` stack) — compiled **once** by the
-        caller, not rebuilt per interval.  ``tune_mask`` restricts which
-        interfaces may decide (default: all interfaces the state's
-        ragged-batch validity masks mark real).  Numpy in, numpy out.
-
-        ``intervene`` (tuned loops only) applies a per-interface
-        :class:`Intervention` counterfactual — ``None`` leaves the
-        compiled program literally unchanged.
-
-        With ``mesh=``, a batch that does not divide the device count is
-        padded with copies of element 0 whose ``tune_mask`` is forced
-        ``False`` (phantom elements never decide); every output is
-        sliced back to the caller's batch before returning.
-        """
-        n_intervals = int(n_intervals)
-        if intervene is not None and not self.tuned:
-            raise ValueError("intervene= requires a tuned loop")
+    def _shape_args(self, table, state, wstate, n_intervals, schedule,
+                    tune_mask, intervene):
+        """:meth:`run`'s host inputs as the program takes them, and the
+        phantom elements padded on for the mesh."""
         if schedule is None:
             schedule = self.neutral_schedule(n_intervals)
             if self.batched:
@@ -697,36 +692,68 @@ class FusedLoop:
                                       dtype=np.asarray(a).dtype)]),
                         intervene)
                 args = args + (intervene,)
+        return args, n_pad
+
+    def run(self, table: WorkloadTable, state: SimState,
+            wstate: WorkloadState, n_intervals: int,
+            schedule: Disturbance | None = None,
+            tune_mask: np.ndarray | None = None,
+            intervene: "Intervention | None" = None) -> FusedLoopResult:
+        """Advance ``n_intervals`` of engine + tuning in one dispatch.
+
+        ``schedule`` is a whole-run :class:`Disturbance` with a flat
+        leading ``(n_intervals * steps, ...)`` time axis (batched: a
+        ``(B, total_ticks, ...)`` stack) — compiled **once** by the
+        caller, not rebuilt per interval.  ``tune_mask`` restricts which
+        interfaces may decide (default: all interfaces the state's
+        ragged-batch validity masks mark real).  Numpy in, numpy out.
+
+        ``intervene`` (tuned loops only) applies a per-interface
+        :class:`Intervention` counterfactual — ``None`` leaves the
+        compiled program literally unchanged.
+
+        With ``mesh=``, a batch that does not divide the device count is
+        padded with copies of element 0 whose ``tune_mask`` is forced
+        ``False`` (phantom elements never decide); every output is
+        sliced back to the caller's batch before returning.
+        """
+        n_intervals = int(n_intervals)
+        if intervene is not None and not self.tuned:
+            raise ValueError("intervene= requires a tuned loop")
+        with span("dial.loop.shape"):
+            args, n_pad = self._shape_args(table, state, wstate, n_intervals,
+                                           schedule, tune_mask, intervene)
 
         with jax.enable_x64(True):
-            if self.mesh is not None:
-                # place inputs *pre-sharded*: jit then donates the
-                # caller's buffers directly instead of donating a
-                # resharding copy (which would leave the originals
-                # alive and defeat donate_argnums)
-                sharding = NamedSharding(
-                    self.mesh, PartitionSpec(self.mesh.axis_names[0]))
-                with self.timers.phase("device_put"):
+            with span("dial.loop.device_put"):
+                if self.mesh is not None:
+                    # place inputs *pre-sharded*: jit then donates the
+                    # caller's buffers directly instead of donating a
+                    # resharding copy (which would leave the originals
+                    # alive and defeat donate_argnums)
+                    sharding = NamedSharding(
+                        self.mesh, PartitionSpec(self.mesh.axis_names[0]))
                     jargs = jax.tree.map(
                         lambda a: jax.device_put(np.asarray(a), sharding),
                         args)
-            else:
-                with self.timers.phase("device_put"):
+                else:
                     jargs = jax.tree.map(jnp.asarray, args)
-            with self.timers.phase("dispatch"):
+            # the call returns once the program is enqueued; its first
+            # call also traces and compiles it, or loads it from the
+            # persistent cache
+            with span("dial.loop.dispatch"):
                 run_fn = (self._get_run(6) if intervene is not None
                           else self._run)
                 out = run_fn(*jargs)
-                out = jax.tree.map(
-                    lambda x: x.block_until_ready()
-                    if hasattr(x, "block_until_ready") else x, out)
+            with span("dial.loop.wait"):
+                out = jax.block_until_ready(out)
         if self.tuned:
             jstate, jws, jtrace, jhist = out
         elif self.trace_config is not None:
             (jstate, jws, jtrace), jhist = out, None
         else:
             (jstate, jws), jtrace, jhist = out, None, None
-        with self.timers.phase("to_host"):
+        with span("dial.loop.to_host"):
             state = jax.tree.map(np.array, jstate)
             if not self.batched:
                 state.now = float(state.now)
@@ -736,16 +763,18 @@ class FusedLoop:
                      if jtrace is not None else None)
             hist = (jax.tree.map(np.array, jhist)
                     if jhist is not None else None)
-        if n_pad:
-            state = unpad_fleet(state, n_pad)
-            wstate = unpad_fleet(wstate, n_pad)
-            trace = unpad_fleet(trace, n_pad) if trace is not None else None
-            hist = unpad_fleet(hist, n_pad) if hist is not None else None
+            if n_pad:
+                state = unpad_fleet(state, n_pad)
+                wstate = unpad_fleet(wstate, n_pad)
+                trace = (unpad_fleet(trace, n_pad) if trace is not None
+                         else None)
+                hist = unpad_fleet(hist, n_pad) if hist is not None else None
+        with span("dial.loop.decode"):
+            decisions = (decisions_from_trace(trace)
+                         if trace is not None and "decided" in trace
+                         else [])
         return FusedLoopResult(
-            state=state, wstate=wstate, trace=trace,
-            decisions=(decisions_from_trace(trace)
-                       if trace is not None and "decided" in trace
-                       else []),
+            state=state, wstate=wstate, trace=trace, decisions=decisions,
             hist=hist,
             interval_seconds=self.steps * self.params.tick,
             n_run=n_intervals)

@@ -1,7 +1,7 @@
 """Device-resident telemetry: tracing must never change a decision,
 the host mirror must produce the same rows as the fused trace, and the
 sinks (JSONL, Chrome trace_event, markdown) must round-trip / validate.
-Also covers the perf-ledger tooling (compare.py, provenance, timers).
+Also covers the perf-ledger tooling (compare.py, provenance, host spans).
 """
 
 import copy
@@ -315,26 +315,56 @@ def test_trace_recipe_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------- #
-# perf ledger: timers, provenance, compare gate
+# perf ledger: host spans, provenance, compare gate
 # ---------------------------------------------------------------------- #
 def test_phase_timers():
-    from repro.obs.timers import PhaseTimers
+    """Host phases are ``obs.spans``: nested spans each count their own
+    calls and seconds, and a compile event goes to the innermost open
+    span (or to "none" outside every span)."""
+    import time
 
-    t = PhaseTimers()
-    with t.phase("dispatch"):
+    import jax
+
+    from repro.obs import spans
+
+    compile_ev = "/jax/core/compile/backend_compile_duration"
+    before = spans.totals()
+    with spans.span("test.outer"):
+        with spans.span("test.inner"):
+            time.sleep(0.01)
+            jax.monitoring.record_event_duration_secs(compile_ev, 0.25)
+            jax.monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/jaxpr_trace_duration", 0.5)
+    with spans.span("test.inner"):
         pass
-    t.add("dispatch", 0.5)
-    t.add("to_host", 0.25)
-    s = t.summary()
-    assert s["dispatch"]["calls"] == 2
-    assert s["dispatch"]["seconds"] >= 0.5
-    assert s["to_host"]["seconds"] == 0.25
-    t.reset()
-    assert t.summary() == {}
+    jax.monitoring.record_event_duration_secs(
+        "/jax/some/other_event", 1.0)          # not a compile event
+    d = spans.since(before)
+    rows = d["spans"]
+    assert rows["test.outer"]["calls"] == 1
+    assert rows["test.inner"]["calls"] == 2
+    assert rows["test.inner"]["seconds"] >= 0.01
+    assert rows["test.outer"]["seconds"] >= rows["test.inner"]["seconds"] \
+        - 1e-3
+    inner, outer = d["compiles"]["test.inner"], d["compiles"]["test.outer"]
+    assert inner["compiles"] == 1 and inner["backend_s"] == 0.25
+    assert inner["cache_loads"] == 1 and inner["cache_s"] == 0.125
+    assert outer["compiles"] == 0 and outer["trace_s"] == 0.5
+    jax.monitoring.record_event_duration_secs(compile_ev, 0.0625)
+    assert spans.since(before)["compiles"]["none"]["backend_s"] == 0.0625
+    # a span closes on an exception too, and the stack unwinds
+    with pytest.raises(RuntimeError):
+        with spans.span("test.raises"):
+            raise RuntimeError("x")
+    assert spans.since(before)["spans"]["test.raises"]["calls"] == 1
+    jax.monitoring.record_event_duration_secs(compile_ev, 0.0625)
+    assert spans.since(before)["compiles"]["none"]["compiles"] == 2
 
 
 def test_collect_provenance():
-    from repro.obs.timers import collect_provenance
+    from repro.obs.provenance import collect_provenance
 
     p = collect_provenance()
     for key in ("git_sha", "platform", "python", "jax_version",
