@@ -6,7 +6,8 @@ the *entire* configuration space against the current history in one shot.
 
 Backends:
     'numpy'  -- DenseForest.predict_proba (always available; the oracle)
-    'jax'    -- jitted gather-based traversal (repro.kernels.gbdt_forest.ops)
+    'jax'    -- jitted traversal (repro.kernels.gbdt_forest.ops): the fleet
+                path scores both forests gather-free in one launch
 
 The batched evaluation (n_oscs x |Theta| rows per tick) is the paper's
 inference hot spot (Table III: ~10-13.5 ms per interface); the TPU

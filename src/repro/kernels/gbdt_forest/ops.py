@@ -1,4 +1,11 @@
-"""Jitted public wrappers for GBDT forest inference."""
+"""Jitted public wrappers for GBDT forest inference.
+
+The paired read/write forests are scored by
+:func:`repro.kernels.gbdt_forest.dense.paired_scorer`, the one scorer of
+the host fleet predictor here and of the device-resident loop
+(:mod:`repro.pfs.loop_jax`); the gather descent in
+:mod:`repro.kernels.gbdt_forest.ref` is its test oracle.
+"""
 
 from __future__ import annotations
 
@@ -9,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.gbdt_forest import ref as _ref
+from repro.kernels.gbdt_forest.dense import paired_scorer
 
 
 def make_predictor(forest):
@@ -112,17 +120,13 @@ def make_fleet_predictor(read_forest, write_forest):
     Python-level agents or with having two models.  Row counts are
     bucketed to powers of two so jit traces a handful of shapes total.
     """
-    feature, threshold, leaf, base, depth, n_features = pair_forests(
+    feature, threshold, leaf, base, _, n_features = pair_forests(
         read_forest, write_forest)
-    feature = jnp.asarray(feature)
-    threshold = jnp.asarray(threshold)
-    leaf = jnp.asarray(leaf)
-    base = jnp.asarray(base)
+    score = paired_scorer(feature, threshold, leaf, base)
 
     @jax.jit
     def _predict(x, op):
-        m = _ref.paired_forest_margin_ref(x.astype(jnp.float32), op, feature,
-                                          threshold, leaf, base, depth)
+        m = score(x.astype(jnp.float32), op)
         return 1.0 / (1.0 + jnp.exp(-jnp.clip(m, -30.0, 30.0)))
 
     def predict(x_read: np.ndarray, x_write: np.ndarray):

@@ -1,8 +1,11 @@
 """Pure-jnp oracle for dense-forest GBDT inference.
 
-Matches :meth:`repro.core.gbdt.DenseForest.predict_margin` bit-for-bit on
-float32 inputs: a static ``depth``-step level-synchronous descent through
-complete binary trees laid out in dense arrays.
+A static ``depth``-step level-synchronous descent through complete binary
+trees laid out in dense arrays, gathering each row's node and leaf at
+every level: the leaves :meth:`repro.core.gbdt.DenseForest.predict_margin`
+reaches on float32 inputs.  :func:`paired_forest_margin_ref` is the oracle
+of the gather-free paired scorer (:mod:`repro.kernels.gbdt_forest.dense`),
+which scores forests deeper than its cut-off with it.
 """
 
 from __future__ import annotations
@@ -93,4 +96,17 @@ def paired_forest_margin_ref(x, op, feature, threshold, leaf, base,
     leaf_forest_off = op.astype(jnp.int32) * (t * n_leaves)
     vals = leaf_flat[(idx - n_internal) + leaf_off[None, :]
                      + leaf_forest_off[:, None]]
-    return vals.sum(axis=1).astype(jnp.float32) + base[op]
+    return tree_sum(vals) + base[op]
+
+
+def tree_sum(vals):
+    """Sum (N, T) per-tree values over trees, tree 0 first.
+
+    A reduction's order is the compiler's choice, and differs with the
+    backend, the shape and the fusion around it; a fixed left-to-right
+    sum gives every scorer of the paired forests the same float32 bits.
+    """
+    margin = jnp.zeros(vals.shape[0], jnp.float32)
+    for t in range(vals.shape[1]):
+        margin = margin + vals[:, t]
+    return margin

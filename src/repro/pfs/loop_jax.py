@@ -17,8 +17,10 @@ This module folds the *entire* closed loop into one compiled program:
                                   (the literal oracle arithmetic, xp=jnp)
       └─ features                 history ‖ θ ‖ Δθ, float64 → float32
                                   (same rounding as the host matrix)
-      └─ forest scoring           :func:`paired_forest_margin_ref` — both
-                                  ops, all interfaces × configs, one pass
+      └─ forest scoring           :func:`paired_scorer`, gather-free —
+                                  both ops, all interfaces × configs,
+                                  one pass (the host fleet predictor's
+                                  scorer, so the same bits)
       └─ Algorithm 1              :func:`repro.core.tuner.score_greedy_arrays`
                                   (the literal oracle reductions, xp=jnp)
       └─ gating + write-back      volume/steadiness masks, knob update on
@@ -66,7 +68,7 @@ from repro.core.metrics import (N_READ, N_WRITE, READ_KNOB_IDX,
                                 WRITE_KNOB_IDX, snapshot_arrays)
 from repro.core.model import DIALModel
 from repro.core.tuner import TunerParams, score_greedy_arrays
-from repro.kernels.gbdt_forest.ref import paired_forest_margin_ref
+from repro.kernels.gbdt_forest.dense import paired_scorer
 from repro.kernels.segment_reduce.ops import make_segment_sum
 from repro.pfs.engine_jax import engine_step_jax
 from repro.pfs.state import (READ, WRITE, Disturbance, SimParams, SimState,
@@ -355,14 +357,11 @@ class FusedLoop:
         n = topo.n_osc
         m = len(space)
         if self.tuned:
-            feature, threshold, leaf, base, depth, n_features = \
+            feature, threshold, leaf, base, _, n_features = \
                 model.paired_arrays()
+            score_forests = paired_scorer(feature, threshold, leaf, base)
             # constants must keep f64 (oracle parity)
             with jax.enable_x64(True):
-                feature = jnp.asarray(feature)
-                threshold = jnp.asarray(threshold)
-                leaf = jnp.asarray(leaf)
-                base = jnp.asarray(base)
                 theta_raw = jnp.asarray(space.as_array())        # f64
                 theta_feats = jnp.asarray(space.as_features())   # log2
             kp1 = self.k + 1
@@ -518,9 +517,7 @@ class FusedLoop:
                         op_rows = jnp.repeat(ops, m)
                         x = jnp.where((op_rows == READ)[:, None], x_r, x_w)
                     with jax.named_scope("dial.forest"):
-                        margin = paired_forest_margin_ref(
-                            x, op_rows, feature, threshold, leaf, base,
-                            depth)
+                        margin = score_forests(x, op_rows)
                         p32 = 1.0 / (1.0 + jnp.exp(
                             -jnp.clip(margin, -30.0, 30.0)))
                         probs = p32.astype(jnp.float64).reshape(n, m)

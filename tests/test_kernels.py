@@ -39,6 +39,96 @@ def test_gbdt_forest_kernel_matches_refs(forest, n):
                                rtol=1e-4, atol=1e-4)
 
 
+def _random_forest(rng, n_trees, depth, n_features, base=0.0):
+    """A dense forest with arbitrary (non-dyadic) leaves."""
+    from repro.core.gbdt import DenseForest
+
+    n_internal = 2 ** depth - 1
+    return DenseForest(
+        feature=rng.integers(0, n_features, size=(n_trees, n_internal),
+                             dtype=np.int32),
+        threshold=rng.normal(size=(n_trees, n_internal)).astype(np.float32),
+        leaf=rng.normal(size=(n_trees, n_internal + 1)).astype(np.float32),
+        base_score=base, depth=depth, n_features=n_features)
+
+
+def _trained_forest(rng, n_trees, depth, n_features):
+    X = rng.normal(size=(1500, n_features))
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(float)
+    return GBDTClassifier(GBDTParams(n_trees=n_trees, max_depth=depth)).fit(
+        X, y).forest
+
+
+def _paired_case(case, forest):
+    """``(read_forest, write_forest, x, op)`` of one scorer case."""
+    rng = np.random.default_rng(7)
+    n = 257
+    if case == "trained":
+        fr, fw = forest, _trained_forest(rng, 24, 5, 30)
+    elif case == "mixed_depth":       # pass-through spines, inert trees
+        fr = _trained_forest(rng, 12, 3, 10)
+        fw = _trained_forest(rng, 20, 5, 14)
+    elif case == "160_trees":
+        fr = _random_forest(rng, 160, 5, 36, base=-0.3)
+        fw = _random_forest(rng, 150, 5, 36, base=0.2)
+    elif case == "deep":              # above the dense cut-off
+        fr = _random_forest(rng, 4, 9, 12, base=0.1)
+        fw = _random_forest(rng, 3, 9, 12)
+    else:
+        fr = _random_forest(rng, 16, 5, 20, base=0.25)
+        fw = _random_forest(rng, 16, 4, 20, base=-0.5)
+    n_feat = max(fr.n_features, fw.n_features)
+    x = rng.normal(size=(n, n_feat)).astype(np.float32)
+    if case == "ties":                # feature values equal to thresholds
+        for f in (fr, fw):
+            t = rng.integers(0, f.n_trees, size=n)
+            j = rng.integers(0, f.feature.shape[1], size=n)
+            x[np.arange(n), f.feature[t, j]] = f.threshold[t, j]
+    elif case == "inf":               # +inf thresholds mid-tree, ±inf, nan
+        for f in (fr, fw):
+            f.threshold[rng.random(f.threshold.shape) < 0.2] = np.inf
+        x[rng.random(x.shape) < 0.05] = np.inf
+        x[rng.random(x.shape) < 0.05] = -np.inf
+        x[rng.random(x.shape) < 0.02] = np.nan
+    elif case == "one_row":
+        x, n = x[:1], 1
+    op = rng.integers(0, 2, size=n).astype(np.int32)
+    if case == "one_row":
+        op[:] = 1
+    x[op == 0, fr.n_features:] = 0.0
+    x[op == 1, fw.n_features:] = 0.0
+    return fr, fw, x, op
+
+
+@pytest.mark.parametrize("case", ["trained", "mixed_depth", "ties", "inf",
+                                  "one_row", "160_trees", "deep"])
+def test_paired_scorer_matches_refs(forest, case):
+    """The paired scorer's margins: bit-equal to the gather reference,
+    and to the unpadded numpy forests within float32 summation; gathers
+    only above the dense depth cut-off."""
+    from repro.kernels.gbdt_forest.dense import (DENSE_MAX_DEPTH,
+                                                 paired_scorer)
+    from repro.kernels.gbdt_forest.ops import pair_forests
+    from repro.kernels.gbdt_forest.ref import paired_forest_margin_ref
+
+    fr, fw, x, op = _paired_case(case, forest)
+    feature, threshold, leaf, base, depth, _ = pair_forests(fr, fw)
+    score = jax.jit(paired_scorer(feature, threshold, leaf, base))
+    got = np.asarray(score(x, op))
+    ref = jax.jit(paired_forest_margin_ref, static_argnums=6)(
+        x, op, feature, threshold, leaf, base, depth)
+    np.testing.assert_array_equal(got, np.asarray(ref))
+
+    for k, f in enumerate((fr, fw)):
+        rows = op == k
+        want = f.predict_margin(x[rows, :f.n_features])
+        bound = np.abs(f.leaf).max(axis=1).sum() + abs(f.base_score)
+        np.testing.assert_allclose(got[rows], want, rtol=0,
+                                   atol=(f.n_trees + 1) * 2.0**-24 * bound)
+    gathers = " gather(" in score.lower(x, op).compile().as_text()
+    assert gathers == (depth > DENSE_MAX_DEPTH)
+
+
 # ---------------------------------------------------------------------- #
 # flash attention
 # ---------------------------------------------------------------------- #

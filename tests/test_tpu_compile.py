@@ -9,6 +9,7 @@ the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -92,9 +93,33 @@ def test_tree_histogram_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_loop_compiles_for_v5e(one_chip, dial_model):
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _gathers_under(text, scope):
+    """Lines of compiled HLO text that gather under a named scope: a
+    gather whose ``op_name`` holds the scope, or a fusion that carries
+    the scope and calls a computation holding a gather."""
+    comp, gathering, lines = None, set(), text.splitlines()
+    for line in lines:
+        if line.endswith("{") and not line.startswith(" "):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+        elif " gather(" in line:
+            gathering.add(comp.lstrip("%"))
+    out = []
+    for line in lines:
+        op = _OP_NAME.search(line)
+        if op is None or scope not in op.group(1):
+            continue
+        calls = re.search(r"calls=%([\w.\-]+)", line)
+        if " gather(" in line or (calls and calls.group(1) in gathering):
+            out.append(line)
+    return out
+
+
+def _compile_fused_loop(one_chip, model, batched):
     """FusedLoop's jitted run at 64 clients x 8 OSTs (512 OSCs), 20
-    intervals of 100 ticks, with its default segment reduction."""
+    intervals of 100 ticks, compiled for one v5e chip."""
     import jax
 
     from repro.lab.scenarios import build, vpic_bdcats_deployment
@@ -102,15 +127,60 @@ def test_fused_loop_compiles_for_v5e(one_chip, dial_model):
 
     b = build(vpic_bdcats_deployment(64, 8))
     steps, n_int = 100, 20
-    loop = FusedLoop(b.params, b.topo, steps, dial_model)
+    loop = FusedLoop(b.params, b.topo, steps, model, batched=batched)
     with jax.enable_x64(True):
-        sched = loop._shape_schedule(b.schedule(0, steps * n_int), n_int)
-        args = _shapes((b.table, b.state, b.wstate, sched,
-                        np.ones(b.topo.n_osc, dtype=bool)), one_chip)
-    compiled = loop.lower(*args).compile()
-    assert "while" in compiled.as_text()          # the interval scan
+        args = (b.table, b.state, b.wstate, b.schedule(0, steps * n_int),
+                np.ones(b.topo.n_osc, dtype=bool))
+        if batched:
+            args = jax.tree.map(lambda a: np.asarray(a)[None], args)
+        table, state, wstate, sched, mask = args
+        args = _shapes((table, state, wstate,
+                        loop._shape_schedule(sched, n_int), mask), one_chip)
+    return loop.lower(*args).compile()
+
+
+def test_fused_loop_compiles_for_v5e(one_chip, dial_model):
+    """The fused loop with its default segment reduction; its forest
+    scoring gathers nothing."""
+    compiled = _compile_fused_loop(one_chip, dial_model, batched=False)
+    text = compiled.as_text()
+    assert "while" in text                        # the interval scan
+    assert "dial.forest" in text
+    assert _gathers_under(text, "dial.forest") == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_batched_fused_loop_scores_without_gathers_for_v5e(one_chip,
+                                                           dial_model):
+    """Vmapped as the lab and the benchmark run it (a batch of one), the
+    forest scoring still gathers nothing."""
+    text = _compile_fused_loop(one_chip, dial_model, batched=True).as_text()
+    assert "dial.forest" in text
+    assert _gathers_under(text, "dial.forest") == []
+
+
+def test_paired_scorer_compiles_for_v5e(one_chip):
+    """The gather-free paired scorer for two trainer-default 160-tree
+    forests of depth 5, over 4,096 interfaces x 24 candidates, in under
+    1 GiB of temporaries."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.gbdt_forest.dense import paired_scorer
+
+    rng = np.random.default_rng(0)
+    t, n_internal, n_features, n = 160, 31, 36, 4096 * 24
+    feature = rng.integers(0, n_features, size=(2, t, n_internal))
+    threshold = rng.normal(size=(2, t, n_internal)).astype(np.float32)
+    leaf = rng.normal(size=(2, t, n_internal + 1)).astype(np.float32)
+    score = paired_scorer(feature, threshold, leaf, np.zeros(2, np.float32))
+    compiled = jax.jit(score).lower(
+        jax.ShapeDtypeStruct((n, n_features), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+    ).compile()
+    assert " gather(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_tree_histogram_vmapped_compiles_for_v5e(one_chip):
